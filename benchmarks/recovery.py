@@ -41,11 +41,12 @@ def run(quick: bool = False):
         rows.append(fmt_row(f"recovery_n{n}", res,
                             {"nodes_per_sec": f"{n / dt:.0f}",
                              "live": int(hist[3])}))
-        # kernel-only validity scan: jnp reference vs Pallas (interpret)
+        # kernel-only validity scan: jnp reference vs Pallas
         persisted = s2.cur
         for tag, use_pallas in (("ref", False), ("pallas", True)):
-            if use_pallas and n > (1 << 12):
-                continue          # interpret mode: keep the grid small
+            if (use_pallas and n > (1 << 12)
+                    and jax.default_backend() != "tpu"):
+                continue          # interpreted off the TPU: keep it small
             t0 = time.perf_counter()
             mask, hist2 = recovery_scan(persisted, use_pallas=use_pallas)
             jax.block_until_ready(hist2)

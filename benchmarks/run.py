@@ -35,6 +35,8 @@ def main() -> None:
             ap.error("a comma-separated --backend sweep is only supported "
                      "with --only bench_shard")
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from benchmarks import (scalability, key_range, read_pct,
                             psync_counts, recovery, checkpoint_bench,
                             bench_hash, bench_shard, bench_queue,

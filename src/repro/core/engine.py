@@ -20,8 +20,9 @@ call:
            at any load factor.
 
 Everything is configured by one frozen, hashable :class:`SetSpec` (capacity,
-algorithm mode, backend, table/bucket geometry, pallas-interpret flag) that
-is passed as a static jit argument -- no loose kwargs.
+algorithm mode, backend, table/bucket geometry) that is passed as a static
+jit argument -- no loose kwargs.  Pallas kernels run compiled on the TPU
+and interpreted elsewhere (:func:`repro.kernels.resolve_interpret`).
 
 The serving-shaped entrypoint is :func:`apply_batch`: a mixed
 contains/insert/remove lane vector executed in ONE jitted dispatch.  Mixed
@@ -91,8 +92,9 @@ def warn_structure(message: str, stacklevel: int = 3) -> None:
             for key in set(registry) - before:
                 registry.pop(key, None)       # undo the dedup record
 
-# f32-exact integer budget of the MXU one-hot gather (see hash_probe.kernel).
-_F32_EXACT = 1 << 24
+# Node-id budget of the MXU byte-plane gather: ids travel as three bytes
+# (see hash_probe.kernel).
+_ID_BUDGET = 1 << 24
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,12 +116,11 @@ class SetSpec:
                   bucket lookup/recovery path, and the probe backend's
                   windowed table lookup (else pure-lax references)
     probe_pallas_lookup
-                  probe backend: route kernel-eligible lookups through the
-                  Pallas ``table_lookup`` one-hot-matmul path.  None (the
+                  probe backend: route lookups through the Pallas
+                  ``table_lookup`` one-hot-matmul path.  None (the
                   default) auto-selects by platform -- the MXU route on
                   TPU, the chunked lax window gather elsewhere (on CPU the
                   matmul sweep is strictly more work than the gather)
-    interpret     pallas_call interpret mode (True for CPU / debugging)
     """
     capacity: int
     mode: str = "soft"
@@ -131,7 +132,6 @@ class SetSpec:
     stash_size: int = 128
     use_pallas: bool = True
     probe_pallas_lookup: Optional[bool] = None
-    interpret: bool = True
 
     def __post_init__(self):
         if self.capacity <= 0:
@@ -145,9 +145,9 @@ class SetSpec:
                                   (self.n_buckets - 1)) != 0:
             raise ValueError("n_buckets must be 0 (derived) or a power of "
                              f"two, got {self.n_buckets}")
-        if self.backend == "bucket" and self.capacity >= _F32_EXACT:
-            raise ValueError("bucket backend: capacity exceeds the f32-exact "
-                             f"node-id budget ({_F32_EXACT})")
+        if self.backend == "bucket" and self.capacity >= _ID_BUDGET:
+            raise ValueError("bucket backend: capacity exceeds the kernel's "
+                             f"node-id budget ({_ID_BUDGET})")
 
     def bucket_geometry(self) -> Tuple[int, int]:
         """Resolved (NB, W) for the bucket backend."""
@@ -219,8 +219,9 @@ class ProbeBackend(_NullIndexMixin):
     """The paper's hash-set experiments: linear probing over SetState.table.
 
     Reads route through the tiled Pallas ``hash_probe`` kernel when
-    selected (``probe_pallas_lookup``; auto == TPU) and the batch geometry
-    allows it (lane-aligned batch, f32-exact node ids): each lane's probe
+    selected (``probe_pallas_lookup``; auto == TPU with a pool inside the
+    kernel's node-id budget, capacity < 2^24; an explicit True past that
+    budget raises): each lane's probe
     window is gathered once into a (B, P) plane pair and becomes its own
     bucket row, so probe shares the MXU one-hot matmul path the bucket
     backend uses.  Otherwise the chunked pure-lax window lookup runs --
@@ -231,15 +232,13 @@ class ProbeBackend(_NullIndexMixin):
     builds_probe_table = True
 
     def lookup(self, spec, state, keys):
-        b = keys.shape[0]
         use = spec.probe_pallas_lookup
         if use is None:                # auto: MXU route on TPU only
-            use = spec.use_pallas and jax.default_backend() == "tpu"
-        if (use and spec.capacity < _F32_EXACT
-                and b % 8 == 0 and (b <= 4096 or b % 4096 == 0)):
+            use = (spec.use_pallas and jax.default_backend() == "tpu"
+                   and spec.capacity < _ID_BUDGET)
+        if use:
             return hp_ops.table_lookup(state.table, state.keys, keys,
-                                       max_probe=spec.max_probe,
-                                       interpret=spec.interpret)
+                                       max_probe=spec.max_probe)
         return DS._lookup_probe(state, keys, max_probe=spec.max_probe)
 
     def update_index(self, spec, phase):
@@ -281,8 +280,7 @@ class BucketBackend:
 
     def lookup(self, spec, state, keys):
         found = hp_ops.lookup(state.bkeys, state.bids, keys,
-                              use_pallas=spec.use_pallas,
-                              interpret=spec.interpret)
+                              use_pallas=spec.use_pallas)
 
         def with_stash(f):
             # only paid while the stash is occupied (lax.cond branch)
@@ -295,8 +293,7 @@ class BucketBackend:
         return lax.cond(state.stash_n > 0, with_stash, lambda f: f, found)
 
     def recover_scan(self, spec, persisted):
-        return rs_ops.recovery_scan(persisted, use_pallas=spec.use_pallas,
-                                    interpret=spec.interpret)
+        return rs_ops.recovery_scan(persisted, use_pallas=spec.use_pallas)
 
     def state_geometry(self, spec):
         nb, w = spec.bucket_geometry()

@@ -35,7 +35,7 @@ the engine's batched lane model (DESIGN.md SS7):
   recovery      head/tail are VOLATILE (rebuilt, never persisted -- the
                 queue-level analogue of the set's volatile index).
                 :func:`recover` classifies persisted stages with the
-                ``recovery_scan`` kernel (Pallas where eligible) and
+                Pallas ``recovery_scan`` kernel and
                 reconstructs: live elements = persisted-VALID slots in
                 ticket order; head = min live ticket (else one past the
                 newest persisted-DELETED ticket); tail = one past the max
@@ -78,13 +78,12 @@ class QueueSpec:
                 read-side helping) | "logfree" (2 per successful op,
                 the link-persist baseline)
     use_pallas  route recovery classification through the Pallas
-                ``recovery_scan`` kernel where the geometry is eligible
-    interpret   pallas_call interpret mode (True for CPU / debugging)
+                ``recovery_scan`` kernel (compiled on the TPU, interpreted
+                elsewhere) instead of its jnp reference
     """
     capacity: int
     mode: str = "soft"
     use_pallas: bool = True
-    interpret: bool = True
 
     def __post_init__(self):
         c = self.capacity
@@ -294,8 +293,7 @@ def recover_impl(persisted: jax.Array, tickets: jax.Array, vals: jax.Array,
     [head, tail); a hole would mean a lost element, so the invariant
     violation latches ``overflow`` instead of passing silently.  No psync
     is ever issued: payloads are already durable."""
-    member, hist = rs_ops.recovery_scan(persisted, use_pallas=spec.use_pallas,
-                                        interpret=spec.interpret)
+    member, hist = rs_ops.recovery_scan(persisted, use_pallas=spec.use_pallas)
     deleted = persisted == DELETED
     any_m = member.any()
     big = jnp.int32(np.iinfo(np.int32).max)
@@ -330,9 +328,8 @@ def recover_impl(persisted: jax.Array, tickets: jax.Array, vals: jax.Array,
 def recover(persisted: jax.Array, tickets: jax.Array, vals: jax.Array,
             stamp: Optional[jax.Array] = None, *,
             spec: QueueSpec) -> Tuple[QueueState, jax.Array]:
-    """Jitted recovery: classification via the ``recovery_scan`` kernel
-    (Pallas where eligible) + head/tail reconstruction.  Returns
-    (state, stage histogram i32[5])."""
+    """Jitted recovery: classification via the ``recovery_scan`` kernel +
+    head/tail reconstruction.  Returns (state, stage histogram i32[5])."""
     return recover_impl(persisted, tickets, vals, stamp, spec=spec)
 
 
@@ -362,8 +359,7 @@ def hybrid_recover_impl(snap: QueueState, persisted: jax.Array,
     valid = delta_idx < n
     gi = jnp.where(valid, delta_idx, 0)
     d_per = jnp.where(valid, persisted[gi], 0)
-    member_d, _ = rs_ops.recovery_scan(d_per, use_pallas=spec.use_pallas,
-                                       interpret=spec.interpret)
+    member_d, _ = rs_ops.recovery_scan(d_per, use_pallas=spec.use_pallas)
     member_d = member_d & valid
 
     scat = jnp.where(valid, delta_idx, n)           # OOB scatter => dropped

@@ -457,17 +457,15 @@ def _group_dispatch(group_fn, state, lanes, *, sspec, groups: int):
     s = sspec.n_shards
     per = s // groups
     if _use_mesh(sspec, groups):
-        # lazy core -> launch import, only on the opt-in multi-device path
-        from repro.launch.mesh import compat_make_mesh, compat_shard_map
-
         def body(st, *rows):
             st, *outs = group_fn(st, *(r[0] for r in rows))
             return (st,) + tuple(o[None] for o in outs)
 
-        mesh = compat_make_mesh((groups,), ("shards",))
+        mesh = jax.make_mesh((groups,), ("shards",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         p = PartitionSpec("shards")
-        return compat_shard_map(body, mesh, in_specs=p, out_specs=p)(
-            state, *lanes)
+        return jax.shard_map(body, mesh=mesh, in_specs=p, out_specs=p,
+                             check_vma=False)(state, *lanes)
     stacked = jax.tree.map(
         lambda x: x.reshape((groups, per) + x.shape[1:]), state)
     out = jax.vmap(group_fn)(stacked, *lanes)

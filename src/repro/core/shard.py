@@ -120,8 +120,10 @@ class ShardSpec:
                     depth 1 (tests/test_pipeline.py); a crash abandons
                     only the staged (never-dispatched, zero-psync) batch
     use_shard_map   partition the vmapped dispatch over a 1-D device mesh
-                    when more than one device is available (opt-in; a
-                    single-device process silently stays on plain vmap)
+                    of the largest power-of-two device count (<= S) the
+                    process has; on one device that is plain vmap.
+                    ``chip_smoke.py --four-chips`` requires the 4-device
+                    mesh and fails instead of running on fewer devices
     """
     base: SetSpec
     n_shards: int = 8
@@ -348,11 +350,11 @@ def _dispatch(vfn, sspec: ShardSpec):
     d = _mesh_devices(sspec)
     if d <= 1:
         return vfn
-    # lazy core -> launch import, only on the opt-in multi-device path
-    from repro.launch.mesh import compat_make_mesh, compat_shard_map
-    mesh = compat_make_mesh((d,), ("shards",))
+    mesh = jax.make_mesh((d,), ("shards",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     p = PartitionSpec("shards")
-    return compat_shard_map(vfn, mesh, in_specs=p, out_specs=p)
+    return jax.shard_map(vfn, mesh=mesh, in_specs=p, out_specs=p,
+                         check_vma=False)
 
 
 def _apply_impl(state: SetState, ops: jax.Array, keys: jax.Array,

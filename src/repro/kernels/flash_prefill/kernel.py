@@ -15,11 +15,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -80,7 +83,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                    static_argnames=("window", "qt", "kt", "interpret"))
 def flash_prefill_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                          *, window: int = 0, qt: int = 256, kt: int = 256,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: Optional[bool] = None) -> jax.Array:
+    interpret = resolve_interpret(interpret)
     b, s, h, d = q.shape
     kv = k.shape[2]
     g = h // kv

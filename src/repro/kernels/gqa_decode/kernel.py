@@ -13,11 +13,14 @@ MXU operates on (G, D) @ (D, ST) tiles; D and ST are 128-multiples.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -61,8 +64,9 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("st", "interpret"))
 def gqa_decode_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                       length: jax.Array, *, st: int = 256,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: Optional[bool] = None) -> jax.Array:
     """q f[B,H,D]; k,v f[B,S,KV,D]; length i32[B] -> f[B,H,D]."""
+    interpret = resolve_interpret(interpret)
     b, h, d = q.shape
     s, kv = k.shape[1], k.shape[2]
     g = h // kv

@@ -17,7 +17,7 @@ kernel ``probe_pallas`` (or the jnp reference).
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -156,7 +156,7 @@ def bucket_remove(bkeys, bids, skeys, sids, stash_n, keys, ids, do):
 
 @functools.partial(jax.jit, static_argnames=("max_probe", "interpret"))
 def table_lookup(table: jax.Array, pool_keys: jax.Array, q_keys: jax.Array,
-                 *, max_probe: int = 128, interpret: bool = True
+                 *, max_probe: int = 128, interpret: Optional[bool] = None
                  ) -> jax.Array:
     """Linear-probe-table lookup routed through the tiled ``probe_pallas``
     MXU kernel (the probe backend's read path, DESIGN.md §2a).
@@ -167,13 +167,13 @@ def table_lookup(table: jax.Array, pool_keys: jax.Array, q_keys: jax.Array,
     linear-probing insert invariant (an entry is always placed at or before
     the first EMPTY of its chain, and EMPTY slots are never created by
     operation -- deletes write TOMB) makes the kernel's any-match join equal
-    to the sequential first-match-before-EMPTY result.  Requires B divisible
-    by 8 (and by 4096 past 4096 rows) and node ids within the f32-exact
-    budget; callers fall back to the lax window lookup otherwise."""
+    to the sequential first-match-before-EMPTY result.  Any batch size (the
+    kernel pads to whole query tiles); node ids must stay within the
+    kernel's 2^24 budget.  ``interpret`` defaults to the platform."""
     t = table.shape[0]
     b = q_keys.shape[0]
     n = pool_keys.shape[0]
-    assert n < (1 << 24), "pool size exceeds the f32-exact node-id budget"
+    assert n < (1 << 24), "pool size exceeds the kernel's node-id budget"
     h = (hash32(q_keys) & jnp.uint32(t - 1)).astype(jnp.int32)
     pos = (h[:, None]
            + jnp.arange(max_probe, dtype=jnp.int32)[None, :]) & (t - 1)
@@ -182,23 +182,14 @@ def table_lookup(table: jax.Array, pool_keys: jax.Array, q_keys: jax.Array,
     wkeys = jnp.where(live, pool_keys[jnp.clip(ids, 0, n - 1)], 0)
     wids = jnp.where(live, ids, EMPTY)                     # mask TOMB too
     rows = jnp.arange(b, dtype=jnp.int32)                  # lane i -> row i
-    bq = 128 if b % 128 == 0 else (8 if b % 8 == 0 else 1)
-    nbt = b if b <= 4096 else 4096
-    assert b % nbt == 0, (b, nbt)
-    return probe_pallas(wkeys, wids, rows, q_keys, bq=bq, nbt=nbt,
-                        interpret=interpret)
+    return probe_pallas(wkeys, wids, rows, q_keys, interpret=interpret)
 
 
-def lookup(bucket_keys, bucket_ids, q_keys, *, use_pallas=True,
-           interpret=True):
+def lookup(bucket_keys, bucket_ids, q_keys, *, use_pallas=True):
+    """Bucket-table lookup: the ``probe_pallas`` kernel, or the jnp
+    reference when ``use_pallas`` is False."""
     nb = bucket_keys.shape[0]
     qb = (hash32(q_keys) % jnp.uint32(nb)).astype(jnp.int32)
     if use_pallas:
-        b = q_keys.shape[0]
-        bq = 128 if b % 128 == 0 else (8 if b % 8 == 0 else 1)
-        # Largest lane-aligned bucket tile that fits VMEM (~2.5 MiB at
-        # NBT=4096, W=8): fewer grid steps amortize per-program overhead.
-        nbt = min(4096, nb)
-        return probe_pallas(bucket_keys, bucket_ids, qb, q_keys,
-                            bq=bq, nbt=nbt, interpret=interpret)
+        return probe_pallas(bucket_keys, bucket_ids, qb, q_keys)
     return probe_ref(bucket_keys, bucket_ids, qb, q_keys)

@@ -8,51 +8,67 @@ member mask, and accumulates a per-stage histogram (the recovery telemetry:
 how many nodes were torn / deleted / live) in a VMEM accumulator that is
 written once at the last grid step.
 
-Tiling: grid (N / NT); stage tile i32[NT] -> mask tile + 5-bin histogram.
-NT = 64k keeps the tile at 256 KiB and the pass fully pipelined on HBM.
+Tiling: the stage vector is padded with -1 (no stage: neither member nor
+counted) and laid out as (rows, 128) lanes; grid (rows / TR), stage tile
+i32[TR, 128] -> mask tile + per-lane histogram rows (8, 128), summed over
+lanes by the wrapper.  Every block is 2-D with (8k, 128) trailing dims, so
+the kernel also lowers under ``jax.vmap`` over a stacked shard axis (the
+batch dim lands in front of the block).  TR = 512 rows keeps the tile at
+256 KiB and the pass fully pipelined on HBM.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 N_STAGES = 5
+LANES = 128
+_HIST_ROWS = 8           # N_STAGES padded to one sublane tile
 
 
 def _scan_kernel(stage_ref, mask_ref, hist_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         hist_ref[...] = jnp.zeros_like(hist_ref)
 
     stage = stage_ref[...]
     mask_ref[...] = (stage == 3).astype(jnp.int32)
-    # 5-bin histogram via compare-and-sum (vector-friendly, no scatter)
-    bins = jnp.arange(N_STAGES, dtype=jnp.int32)
-    counts = jnp.sum((stage[None, :] == bins[:, None]).astype(jnp.int32),
-                     axis=1)
-    hist_ref[...] = hist_ref[...] + counts
+    for b in range(N_STAGES):
+        hist_ref[b:b + 1, :] += jnp.sum((stage == b).astype(jnp.int32),
+                                        axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("nt", "interpret"))
 def scan_pallas(persisted: jax.Array, *, nt: int = 65536,
-                interpret: bool = True):
+                interpret: Optional[bool] = None):
+    """persisted i32[N] -> (member mask bool[N], stage histogram i32[5]).
+
+    ``nt`` is the tile in stages (rounded to whole 8x128 tiles); any N.
+    ``interpret`` defaults to the platform."""
+    interpret = resolve_interpret(interpret)
     n = persisted.shape[0]
-    nt = min(nt, n)
-    assert n % nt == 0, (n, nt)
-    grid = (n // nt,)
+    rows = pl.cdiv(n, LANES)
+    tr = max(8, (nt // LANES) // 8 * 8)
+    if tr >= rows:
+        tr = rows                                 # one block: the whole array
+    rows_p = pl.cdiv(rows, tr) * tr
+    x = jnp.pad(persisted, (0, rows_p * LANES - n),
+                constant_values=-1).reshape(rows_p, LANES)
     mask, hist = pl.pallas_call(
         _scan_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((nt,), lambda i: (i,))],
-        out_specs=[pl.BlockSpec((nt,), lambda i: (i,)),
-                   pl.BlockSpec((N_STAGES,), lambda i: (0,))],
-        out_shape=[jax.ShapeDtypeStruct((n,), jnp.int32),
-                   jax.ShapeDtypeStruct((N_STAGES,), jnp.int32)],
+        grid=(rows_p // tr,),
+        in_specs=[pl.BlockSpec((tr, LANES), lambda i: (i, 0))],
+        out_specs=[pl.BlockSpec((tr, LANES), lambda i: (i, 0)),
+                   pl.BlockSpec((_HIST_ROWS, LANES), lambda i: (0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((rows_p, LANES), jnp.int32),
+                   jax.ShapeDtypeStruct((_HIST_ROWS, LANES), jnp.int32)],
         interpret=interpret,
-    )(persisted)
-    return mask.astype(jnp.bool_), hist
+    )(x)
+    return (mask.reshape(-1)[:n].astype(jnp.bool_),
+            hist[:N_STAGES].sum(axis=1))

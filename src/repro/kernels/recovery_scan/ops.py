@@ -1,18 +1,12 @@
-"""Jit'd wrapper: full recovery = scan kernel + table rebuild."""
+"""Recovery classification: the Pallas scan kernel or its jnp reference."""
 from __future__ import annotations
-
-import jax.numpy as jnp
 
 from repro.kernels.recovery_scan.kernel import scan_pallas
 from repro.kernels.recovery_scan.ref import scan_ref
 
 
-def recovery_scan(persisted, *, use_pallas=True, interpret=True):
-    if use_pallas and persisted.shape[0] % 8 == 0:
-        nt = persisted.shape[0]
-        for cand in (65536, 8192, 1024, 128, 8):
-            if persisted.shape[0] % cand == 0:
-                nt = cand
-                break
-        return scan_pallas(persisted, nt=nt, interpret=interpret)
+def recovery_scan(persisted, *, use_pallas=True):
+    """persisted i32[N] -> (member mask bool[N], stage histogram i32[5])."""
+    if use_pallas:
+        return scan_pallas(persisted)
     return scan_ref(persisted)

@@ -45,6 +45,7 @@ from repro.core import (DurableMap, DurableQueue, QueueSpec,
                         ShardedDurableMap, SetSpec)
 from repro.core import queue as Q
 from repro.core.engine import OP_CONTAINS, OP_INSERT, OP_NOP, OP_REMOVE
+from repro.launch.compile_cache import use_compile_cache
 from repro.obs import JSONLSink, MetricsRegistry, bench_meta
 
 
@@ -354,6 +355,7 @@ def main(argv=None):
     ap.add_argument("--quick", action="store_true",
                     help="CI smoke shape: 20s at a small geometry")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     try:
         utils = [float(u) for u in str(args.utilization).split(",")

@@ -8,36 +8,12 @@ from __future__ import annotations
 import jax
 
 
-def compat_make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` across jax versions: ``axis_types`` /
-    ``jax.sharding.AxisType`` only exist in newer releases, and the default
-    (Auto) is what every call site here wants anyway."""
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
-
-
-def compat_shard_map(fn, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions: newer jax exposes ``jax.shard_map``
-    with ``check_vma``; older ships ``jax.experimental.shard_map`` with
-    ``check_rep``.  Replication checking is disabled in both (the call sites
-    here partition everything)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """16x16 chips per pod; the multi-pod mesh adds a leading 2-pod axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
@@ -45,4 +21,5 @@ def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
     n = len(jax.devices())
     data = min(data, n)
     model = min(model, max(n // data, 1))
-    return compat_make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

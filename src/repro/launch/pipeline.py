@@ -57,10 +57,10 @@ def gpipe_fn(stage_fn: Callable[[Any, jax.Array], jax.Array],
         return outs
 
     def run(stage_params, x_micro):
-        from repro.launch.mesh import compat_shard_map
-        return compat_shard_map(
-            local, mesh,
+        return jax.shard_map(
+            local, mesh=mesh,
             in_specs=(P(axis), P(*(None,) * x_micro.ndim)),
-            out_specs=P(*(None,) * x_micro.ndim))(stage_params, x_micro)
+            out_specs=P(*(None,) * x_micro.ndim),
+            check_vma=False)(stage_params, x_micro)
 
     return run

@@ -49,6 +49,7 @@ import numpy as np
 from repro.configs.base import get_config
 from repro.core import (DurableMap, DurableQueue, ElasticShardedMap,
                         QueueSpec, ShardedDurableMap, SetSpec)
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as M
 from repro.models.sharding import CPU_CTX
 from repro.obs import MetricsRegistry
@@ -135,6 +136,7 @@ def main(argv=None):
             ap.error("--autosplit requires --router v2 and --pipeline 1 "
                      "(the split frontier commits at dispatch boundaries)")
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     prefill_step, decode_step = TS.make_serve_steps(cfg, CPU_CTX)

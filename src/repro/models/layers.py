@@ -231,12 +231,11 @@ def _sharded_flash_decode(ctx: ShardCtx, q, ck, cv, valid_len):
         out = acc_g / jnp.maximum(l_g, 1e-30)
         return out.reshape(b, 1, h, d).astype(qx.dtype)
 
-    from repro.launch.mesh import compat_shard_map
-    f = compat_shard_map(
-        local, mesh,
+    f = jax.shard_map(
+        local, mesh=mesh,
         in_specs=(P(dp, None, None, None), P(dp, tp, None, None),
                   P(dp, tp, None, None), P(dp)),
-        out_specs=P(dp, None, None, None))
+        out_specs=P(dp, None, None, None), check_vma=False)
     return f(q, ck, cv, valid_len)
 
 

@@ -44,6 +44,11 @@ def git_commit() -> str:
 
 
 def bench_meta() -> dict:
+    """Provenance, including the device the numbers were taken on."""
     import jax
+    devices = jax.devices()
     return {"git_commit": git_commit(), "jax_version": jax.__version__,
-            "schema_version": SCHEMA_VERSION}
+            "schema_version": SCHEMA_VERSION,
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices)}

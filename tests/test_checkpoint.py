@@ -113,8 +113,7 @@ def test_elastic_restore_new_sharding(tmp_path):
     m = CheckpointManager(str(tmp_path), keep=2)
     t = {"w": np.arange(16, dtype=np.float32).reshape(4, 4)}
     m.save(1, t)
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
     sh = {"w": jax.sharding.NamedSharding(
         mesh, jax.sharding.PartitionSpec("data", None))}
     like = {"w": jax.ShapeDtypeStruct((4, 4), jnp.float32)}

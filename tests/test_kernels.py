@@ -75,3 +75,15 @@ def test_gqa_decode_masks_empty_tail():
                          jnp.full((b, s - 10, kv, d), 100.0)], axis=1)
     out = gqa_decode_pallas(q, k, v, jnp.array([10], jnp.int32))
     np.testing.assert_allclose(np.array(out), 1.0, atol=1e-5)
+
+
+def test_interpret_follows_the_platform(monkeypatch):
+    """Interpreted off the TPU, compiled on it; an interpret request on
+    the TPU raises instead of running the interpreter there."""
+    from repro.kernels import resolve_interpret
+    assert resolve_interpret() is (jax.default_backend() != "tpu")
+    assert resolve_interpret(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret() is False
+    with pytest.raises(ValueError, match="interpret"):
+        resolve_interpret(True)

@@ -15,11 +15,11 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.launch.mesh import compat_make_mesh
+    AUTO = jax.sharding.AxisType.Auto
 
     # ---- 1) GPipe over 4 stages matches sequential ----
     from repro.launch.pipeline import gpipe_fn
-    mesh_p = compat_make_mesh((4,), ("pipe",))
+    mesh_p = jax.make_mesh((4,), ("pipe",), axis_types=(AUTO,))
     rng = np.random.default_rng(0)
     ws = jnp.asarray(rng.standard_normal((4, 8, 8)) * 0.3, jnp.float32)
     xs = jnp.asarray(rng.standard_normal((6, 2, 8)), jnp.float32)
@@ -41,7 +41,7 @@ SCRIPT = textwrap.dedent("""
     from repro.optim import adamw
     from repro.train import steps as TS
 
-    mesh = compat_make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AUTO,) * 2)
     cfg = get_config("qwen3-32b-smoke")
     shape = ShapeConfig("t", 32, 8, "train")
     ctx = make_shard_ctx(cfg, shape, mesh)
@@ -75,7 +75,7 @@ SCRIPT = textwrap.dedent("""
     d = tempfile.mkdtemp()
     mgr = CheckpointManager(d, keep=2)
     mgr.save(1, jax.tree.map(np.asarray, state2.params))
-    mesh2 = compat_make_mesh((2, 4), ("data", "model"))
+    mesh2 = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AUTO,) * 2)
     ctx2 = make_shard_ctx(cfg, shape, mesh2)
     psh2 = to_shardings(mesh2, param_pspecs(cfg, ctx2, mesh=mesh2))
     like = M.abstract_params(cfg)

@@ -293,3 +293,14 @@ def test_scratch_pool_releases_counter():
     da = stats1["acquires"] - stats0["acquires"]
     dr = stats1["releases"] - stats0["releases"]
     assert da >= 1 and da == dr           # synchronous path: no leak
+
+
+def test_bench_meta_names_the_device():
+    """Every BENCH payload names the device its numbers came from."""
+    import jax
+    from repro.obs import bench_meta
+    meta = bench_meta()
+    dev = jax.devices()
+    assert meta["platform"] == dev[0].platform
+    assert meta["device_kind"] == dev[0].device_kind
+    assert meta["device_count"] == len(dev)

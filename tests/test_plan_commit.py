@@ -192,7 +192,7 @@ def test_psync_counter_matches_oracle_trace(backend, mode):
 
 def test_probe_pallas_lookup_matches_lax():
     """use_pallas True/False must be observationally identical for the
-    probe backend on kernel-eligible (8-aligned) batches."""
+    probe backend."""
     rng = np.random.default_rng(9)
     probes = rng.integers(0, 80, 32).astype(np.int32)
     keys = np.arange(64, dtype=np.int32)
@@ -227,16 +227,22 @@ def test_probe_backend_reaches_pallas_kernel(monkeypatch):
 
 
 def test_probe_small_batch_falls_back_to_lax(monkeypatch):
-    """Lane-misaligned batches must silently take the exact lax window
-    lookup, not crash the kernel's tiling asserts."""
-    def boom(*a, **k):                            # pragma: no cover
-        raise AssertionError("pallas route taken for misaligned batch")
+    """A batch that is not a whole 128-lane tile no longer falls back to
+    the lax lookup: it takes the kernel too, the wrapper pads the queries,
+    and the padding never matches."""
+    calls = {"probe": 0}
+    real_probe = hp_ops.probe_pallas
 
-    monkeypatch.setattr(hp_ops, "probe_pallas", boom)
+    def probe_wrap(*a, **k):
+        calls["probe"] += 1
+        return real_probe(*a, **k)
+
+    monkeypatch.setattr(hp_ops, "probe_pallas", probe_wrap)
     m = DurableMap(SetSpec(capacity=168, mode="soft", backend="probe",
                            probe_pallas_lookup=True))
-    m.insert([1, 2, 3])                           # b == 3: lax path
+    m.insert([1, 2, 3])                           # b == 3
     assert list(np.array(m.contains([1, 4, 3]))) == [True, False, True]
+    assert calls["probe"] >= 1, "ragged batch left the kernel route"
 
 
 def test_plan_insert_classification():

@@ -299,7 +299,7 @@ def test_recovery_latches_fifo_hole():
 
 
 def test_recovery_pallas_matches_ref():
-    spec_p = QueueSpec(capacity=128, use_pallas=True, interpret=True)
+    spec_p = QueueSpec(capacity=128, use_pallas=True)
     spec_r = QueueSpec(capacity=128, use_pallas=False)
     q = DurableQueue(spec_p)
     q.enqueue(np.arange(100, dtype=np.int32))

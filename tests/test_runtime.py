@@ -8,7 +8,6 @@ import numpy as np
 import pytest
 
 from repro.data.pipeline import SyntheticTokens, Prefetcher
-from repro.launch.mesh import compat_make_mesh, compat_shard_map
 from repro.runtime.ft import StragglerMonitor, ResilientLoop
 from repro.store.checkpoint import CheckpointManager
 from repro.optim.compress import compressed_psum, quantize, dequantize
@@ -86,15 +85,15 @@ def test_quantize_roundtrip():
 
 def test_compressed_psum_error_feedback():
     """int8 all-reduce with error feedback: mean error shrinks vs one-shot."""
-    mesh = compat_make_mesh((1,), ("d",))
+    mesh = jax.make_mesh((1,), ("d",), axis_types=(jax.sharding.AxisType.Auto,))
 
     def body(g, r):
         return compressed_psum(g, r, "d")
 
-    f = jax.jit(compat_shard_map(
-        body, mesh,
+    f = jax.jit(jax.shard_map(
+        body, mesh=mesh,
         in_specs=(jax.sharding.PartitionSpec(),) * 2,
-        out_specs=(jax.sharding.PartitionSpec(),) * 2))
+        out_specs=(jax.sharding.PartitionSpec(),) * 2, check_vma=False))
     rng = np.random.default_rng(1)
     g = jnp.asarray(rng.standard_normal(512), jnp.float32)
     r = jnp.zeros(512)
@@ -114,7 +113,7 @@ def test_gpipe_matches_sequential():
     n = min(4, len(jax.devices()))
     if n < 2:
         pytest.skip("needs >=2 local devices for a pipeline")
-    mesh = compat_make_mesh((n,), ("pipe",))
+    mesh = jax.make_mesh((n,), ("pipe",), axis_types=(jax.sharding.AxisType.Auto,))
     rng = np.random.default_rng(0)
     ws = jnp.asarray(rng.standard_normal((n, 8, 8)) * 0.3, jnp.float32)
     xs = jnp.asarray(rng.standard_normal((6, 2, 8)), jnp.float32)

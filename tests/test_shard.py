@@ -281,9 +281,7 @@ def test_sharded_bucket_backend_reaches_pallas_kernels(monkeypatch):
 
     monkeypatch.setattr(hp_ops, "probe_pallas", probe_wrap)
     monkeypatch.setattr(rs_ops, "scan_pallas", scan_wrap)
-    # unique capacity => unique ShardSpec => fresh trace hits the wrappers;
-    # per-shard pool (288/4 = 72) stays 8-aligned so recovery_scan takes the
-    # Pallas path
+    # unique capacity => unique ShardSpec => fresh trace hits the wrappers
     m = ShardedDurableMap(SetSpec(capacity=288, mode="soft",
                                   backend="bucket"), n_shards=4)
     m.insert(np.arange(10))
