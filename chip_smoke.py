@@ -175,7 +175,8 @@ def phase_spine(backend: str, seed: int, duration: float = 10.0) -> None:
     check(p["requests_completed"] > 0, f"spine[{backend}]: no requests")
     check(p["meta"]["platform"] == jax.devices()[0].platform,
           f"spine[{backend}]: meta names the wrong device")
-    say(f"b spine[{backend}] ok: rounds={p['spans_ms']['force']['count']} "
+    rounds = p['spans_ms']['spine.force']['count']
+    say(f"b spine[{backend}] ok: rounds={rounds} "
         f"requests={p['requests_completed']} "
         f"psync_per_op={p['psync_per_op']} wall_s={time.perf_counter() - t0}")
 

@@ -52,6 +52,7 @@ from repro.core.durable_set import SetState, MODES
 from repro.core.nvm import FREE, VALID
 from repro.kernels.hash_probe import ops as hp_ops
 from repro.kernels.recovery_scan import ops as rs_ops
+from repro.obs.metrics import span
 
 # Mixed-batch op codes for apply_batch.  OP_NOP matches no phase, so a lane
 # carrying it is an exact no-op (no state change, no psync, no n_ops, result
@@ -856,7 +857,8 @@ class DurableMap(MetricsMixin):
         probe chain past ``max_probe``, or a bucket-backend stash spill past
         ``stash_size``.  Data may be unreachable from that point on --
         detectable, never silent (DESIGN.md §5)."""
-        return bool(self.state.overflow)
+        with span("registry.sync.overflow"):
+            return bool(self.state.overflow)
 
     def _check_overflow(self):
         """One-shot warning when a mutating op latches ``state.overflow``

@@ -66,6 +66,7 @@ from repro.core.durable_set import MODES
 from repro.core.engine import MetricsMixin, warn_structure
 from repro.core.nvm import (FREE, VALID, DELETED, crash_persisted_stage)
 from repro.kernels.recovery_scan import ops as rs_ops
+from repro.obs.metrics import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -408,6 +409,15 @@ def hybrid_recover(snap: QueueState, persisted: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+def _read_vals_ok(vals: jax.Array, ok: jax.Array):
+    """The host reads of a dequeue or peek, one sync span each."""
+    with span("queue.sync.vals"):
+        vals = np.asarray(vals)
+    with span("queue.sync.ok"):
+        ok = np.asarray(ok)
+    return vals, ok
+
+
 class DurableQueue(MetricsMixin):
     """Object API over the durable ring queue (single-controller usage).
 
@@ -443,7 +453,8 @@ class DurableQueue(MetricsMixin):
         """True once the latch fired: an enqueue was rejected on a full
         ring, or recovery found a FIFO-range hole.  Detectable, never
         silent (the queue analogue of ``DurableMap.overflowed``)."""
-        return bool(self.state.overflow)
+        with span("queue.sync.overflow"):
+            return bool(self.state.overflow)
 
     def _check_overflow(self):
         if not self._overflow_warned and self.overflowed:
@@ -456,23 +467,28 @@ class DurableQueue(MetricsMixin):
 
     def enqueue(self, vals):
         vals = jnp.asarray(vals, jnp.int32)
-        self.state, ok, tickets = enqueue(self.state, vals, spec=self.spec)
-        self.last_tickets = np.asarray(tickets)
+        with span("queue.enqueue"):
+            self.state, ok, tickets = enqueue(self.state, vals,
+                                              spec=self.spec)
+        with span("queue.sync.tickets"):
+            self.last_tickets = np.asarray(tickets)
         self._check_overflow()
         return ok
 
     def dequeue(self, n: int, default: int = 0):
         """Pop up to ``n`` elements; returns (values, ok) np arrays."""
         want = jnp.ones((n,), jnp.bool_)
-        self.state, vals, ok, _ = dequeue(self.state, want, spec=self.spec,
-                                          default=default)
-        return np.asarray(vals), np.asarray(ok)
+        with span("queue.dequeue"):
+            self.state, vals, ok, _ = dequeue(self.state, want,
+                                              spec=self.spec,
+                                              default=default)
+        return _read_vals_ok(vals, ok)
 
     def peek(self, n: int, default: int = 0):
         """Read up to ``n`` head elements without consuming (no psync)."""
         want = jnp.ones((n,), jnp.bool_)
         vals, ok, _ = peek(self.state, want, spec=self.spec, default=default)
-        return np.asarray(vals), np.asarray(ok)
+        return _read_vals_ok(vals, ok)
 
     def crash_and_recover(self, u=None):
         if u is None:

@@ -82,6 +82,7 @@ from repro.core.engine import (MetricsMixin, OP_CONTAINS, OP_INSERT, OP_NOP,
                                OP_REMOVE, SetSpec)
 from repro.core.nvm import FREE, VALID
 from repro.core.shard import ShardSpec, ShardedDurableMap, np_shard_of
+from repro.obs.metrics import span
 
 
 class ResizeCapacityError(RuntimeError):
@@ -686,9 +687,13 @@ class ElasticShardedMap(MetricsMixin):
     @property
     def overflowed(self) -> bool:
         if not self.migrating:
-            return bool(np.asarray(self.map.state.overflow).any())
-        o, n = self._masked(np.asarray(self.map.state.overflow),
-                            np.asarray(self.target.state.overflow))
+            with span("registry.sync.overflow"):
+                return bool(np.asarray(self.map.state.overflow).any())
+        with span("registry.sync.overflow"):
+            old = np.asarray(self.map.state.overflow)
+        with span("registry.sync.overflow"):
+            new = np.asarray(self.target.state.overflow)
+        o, n = self._masked(old, new)
         return bool(o.any()) or bool(n.any())
 
     def _overflow_message(self) -> str:

@@ -75,6 +75,7 @@ from jax.sharding import PartitionSpec
 from repro.core import engine as E
 from repro.core.engine import OP_CONTAINS, OP_NOP
 from repro.core.nvm import hash32, np_hash32
+from repro.obs.metrics import span
 
 if TYPE_CHECKING:                                   # pragma: no cover
     from repro.core.shard import ShardSpec
@@ -305,58 +306,62 @@ def host_route(sspec, ops: np.ndarray, keys: np.ndarray,
     once the batch has been forced, so steady-state routing performs no
     grid allocation.
     """
-    ops = np.asarray(ops, np.int32)
-    keys = np.asarray(keys, np.int32)
-    values = np.asarray(values, np.int32)
-    b = int(keys.shape[0])
-    s = sspec.n_shards
-    d = resolve_groups(sspec)
-    per = s // d
+    with span("registry.route"):
+        ops = np.asarray(ops, np.int32)
+        keys = np.asarray(keys, np.int32)
+        values = np.asarray(values, np.int32)
+        b = int(keys.shape[0])
+        s = sspec.n_shards
+        d = resolve_groups(sspec)
+        per = s // d
 
-    row = _np_row_of(keys, sspec, d)
-    real = ops != OP_NOP
-    occupancy = np.bincount(row[real], minlength=s)
-    max_occ = int(occupancy.max()) if b else 0
-    lane_budget = adaptive_lane_budget(sspec, max(b, 1), max_occ)
+        row = _np_row_of(keys, sspec, d)
+        real = ops != OP_NOP
+        occupancy = np.bincount(row[real], minlength=s)
+        max_occ = int(occupancy.max()) if b else 0
+        lane_budget = adaptive_lane_budget(sspec, max(b, 1), max_occ)
 
-    if d == 1 and b and real.all():
-        # single-group, no caller padding: the sub-batch IS the batch
-        # (order preserved) -- skip the split/scatter, but still pad to
-        # the pow2 Bd bucket so live shapes match what precompile traced
-        bd = _pow2_at_least(b)
-        sc = _POOL.acquire(1, bd, b)
-        sc.d_ops[0, :b] = ops
-        sc.d_ops[0, b:] = OP_NOP
-        sc.d_keys[0, :b] = keys
-        sc.d_keys[0, b:] = 0
-        sc.d_vals[0, :b] = values
-        sc.d_vals[0, b:] = 0
-        return RoutePlan(sc.d_ops, sc.d_keys, sc.d_vals, _cached_arange(b),
-                         1, lane_budget, max_occ, occupancy, sc)
+        if d == 1 and b and real.all():
+            # single-group, no caller padding: the sub-batch IS the batch
+            # (order preserved) -- skip the split/scatter, but still pad to
+            # the pow2 Bd bucket so live shapes match what precompile traced
+            bd = _pow2_at_least(b)
+            sc = _POOL.acquire(1, bd, b)
+            sc.d_ops[0, :b] = ops
+            sc.d_ops[0, b:] = OP_NOP
+            sc.d_keys[0, :b] = keys
+            sc.d_keys[0, b:] = 0
+            sc.d_vals[0, :b] = values
+            sc.d_vals[0, b:] = 0
+            return RoutePlan(sc.d_ops, sc.d_keys, sc.d_vals,
+                             _cached_arange(b), 1, lane_budget, max_occ,
+                             occupancy, sc)
 
-    gid = row // per
-    counts = np.bincount(gid[real], minlength=d)
-    bd = _pow2_at_least(max(int(counts.max()) if b else 0, 1))
+        gid = row // per
+        counts = np.bincount(gid[real], minlength=d)
+        bd = _pow2_at_least(max(int(counts.max()) if b else 0, 1))
 
-    sc = _POOL.acquire(d, bd, b)
-    d_ops, d_keys, d_vals, slot = sc.d_ops, sc.d_keys, sc.d_vals, sc.slot
-    d_ops.fill(OP_NOP)
-    d_keys.fill(0)
-    d_vals.fill(0)
-    slot.fill(-1)
-    if b:
-        # stable group-major order; rank within group = sub-batch position
-        lanes = np.flatnonzero(real)
-        order = lanes[np.argsort(gid[lanes], kind="stable")]
-        g_sorted = gid[order]
-        seg0 = np.searchsorted(g_sorted, np.arange(d))
-        rank = np.arange(order.size) - seg0[g_sorted]
-        d_ops[g_sorted, rank] = ops[order]
-        d_keys[g_sorted, rank] = keys[order]
-        d_vals[g_sorted, rank] = values[order]
-        slot[order] = g_sorted.astype(np.int64) * bd + rank
-    return RoutePlan(d_ops, d_keys, d_vals, slot, d, lane_budget, max_occ,
-                     occupancy, sc)
+        sc = _POOL.acquire(d, bd, b)
+        d_ops, d_keys, d_vals, slot = (sc.d_ops, sc.d_keys, sc.d_vals,
+                                       sc.slot)
+        d_ops.fill(OP_NOP)
+        d_keys.fill(0)
+        d_vals.fill(0)
+        slot.fill(-1)
+        if b:
+            # stable group-major order; rank within group = sub-batch
+            # position
+            lanes = np.flatnonzero(real)
+            order = lanes[np.argsort(gid[lanes], kind="stable")]
+            g_sorted = gid[order]
+            seg0 = np.searchsorted(g_sorted, np.arange(d))
+            rank = np.arange(order.size) - seg0[g_sorted]
+            d_ops[g_sorted, rank] = ops[order]
+            d_keys[g_sorted, rank] = keys[order]
+            d_vals[g_sorted, rank] = values[order]
+            slot[order] = g_sorted.astype(np.int64) * bd + rank
+        return RoutePlan(d_ops, d_keys, d_vals, slot, d, lane_budget,
+                         max_occ, occupancy, sc)
 
 
 def host_gather(grid, slot: np.ndarray, fill) -> np.ndarray:
@@ -546,8 +551,9 @@ class InFlight:
 
     Holds the device futures of the jitted stage-2 program plus the
     stage-1 :class:`RoutePlan` needed to invert them.  ``force()``
-    performs the (only) host sync, returns the per-lane numpy results,
-    and recycles the plan's scratch set.  ``kind`` is "apply"
+    performs the host syncs (one read per output, each inside its
+    ``registry.sync.*`` span), returns the per-lane numpy results, and
+    recycles the plan's scratch set.  ``kind`` is "apply"
     (``force() -> (results bool[B], dropped, drop_mask bool[B])``) or
     "get" (``force() -> (values i32[B], present bool[B], dropped,
     drop_mask bool[B])``).  ``drop_mask[i]`` is True exactly when real
@@ -570,31 +576,39 @@ class InFlight:
 
     def force(self):
         if self._forced is None:
-            plan = self.plan
-            if self.kind == "apply":
-                if self.outs is None:
-                    self._forced = (np.zeros((0,), bool), 0,
-                                    np.zeros((0,), bool))
-                else:
-                    res, dropped, kept = self.outs
-                    self._forced = (host_gather(res, plan.slot, False),
-                                    int(np.asarray(dropped).sum()),
-                                    ~host_gather(kept, plan.slot, True))
-            else:
-                if self.outs is None:
-                    self._forced = (np.zeros((0,), np.int32),
-                                    np.zeros((0,), bool), 0,
-                                    np.zeros((0,), bool))
-                else:
-                    vals, pres, dropped, kept = self.outs
-                    self._forced = (
-                        host_gather(vals, plan.slot, np.int32(self.default)),
-                        host_gather(pres, plan.slot, False),
-                        int(np.asarray(dropped).sum()),
-                        ~host_gather(kept, plan.slot, True))
-            self.outs = None
-            _POOL.release(plan.scratch)
+            with span("registry.force"):
+                self._forced = self._gather()
+                self.outs = None
+                _POOL.release(self.plan.scratch)
         return self._forced
+
+    def _gather(self):
+        slot = self.plan.slot
+        if self.kind == "apply":
+            if self.outs is None:
+                return np.zeros((0,), bool), 0, np.zeros((0,), bool)
+            res, dropped, kept = self.outs
+            return (host_gather(_read("registry.sync.results", res), slot,
+                                False),
+                    int(_read("registry.sync.dropped", dropped).sum()),
+                    ~host_gather(_read("registry.sync.kept", kept), slot,
+                                 True))
+        if self.outs is None:
+            return (np.zeros((0,), np.int32), np.zeros((0,), bool), 0,
+                    np.zeros((0,), bool))
+        vals, pres, dropped, kept = self.outs
+        return (host_gather(_read("registry.sync.values", vals), slot,
+                            np.int32(self.default)),
+                host_gather(_read("registry.sync.present", pres), slot,
+                            False),
+                int(_read("registry.sync.dropped", dropped).sum()),
+                ~host_gather(_read("registry.sync.kept", kept), slot, True))
+
+
+def _read(name: str, x) -> np.ndarray:
+    """One device-to-host read, inside its ``registry.sync.*`` span."""
+    with span(name):
+        return np.asarray(x)
 
 
 def dispatch_plan(state, plan: RoutePlan, *, sspec, kind: str = "apply",
@@ -606,17 +620,20 @@ def dispatch_plan(state, plan: RoutePlan, *, sspec, kind: str = "apply",
         _POOL.release(plan.scratch)
         return state, InFlight(kind, plan._replace(scratch=None), None,
                                default)
-    if kind == "apply":
-        state, res, dropped, kept = _apply_v2(
-            state, jnp.asarray(plan.d_ops), jnp.asarray(plan.d_keys),
-            jnp.asarray(plan.d_vals), sspec=sspec, groups=plan.groups,
-            lane_budget=plan.lane_budget)
-        return state, InFlight(kind, plan, (res, dropped, kept))
-    state, vals, pres, dropped, kept = _get_v2(
-        state, jnp.asarray(plan.d_keys),
-        jnp.asarray(plan.d_ops) == OP_CONTAINS, sspec=sspec,
-        groups=plan.groups, lane_budget=plan.lane_budget, default=default)
-    return state, InFlight(kind, plan, (vals, pres, dropped, kept), default)
+    with span("registry.launch"):
+        if kind == "apply":
+            state, res, dropped, kept = _apply_v2(
+                state, jnp.asarray(plan.d_ops), jnp.asarray(plan.d_keys),
+                jnp.asarray(plan.d_vals), sspec=sspec, groups=plan.groups,
+                lane_budget=plan.lane_budget)
+            return state, InFlight(kind, plan, (res, dropped, kept))
+        state, vals, pres, dropped, kept = _get_v2(
+            state, jnp.asarray(plan.d_keys),
+            jnp.asarray(plan.d_ops) == OP_CONTAINS, sspec=sspec,
+            groups=plan.groups, lane_budget=plan.lane_budget,
+            default=default)
+        return state, InFlight(kind, plan, (vals, pres, dropped, kept),
+                               default)
 
 
 def apply_batch_v2_async(state, ops, keys, values, *, sspec):

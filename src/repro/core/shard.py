@@ -77,6 +77,7 @@ from repro.core.durable_set import SetState
 from repro.core.engine import (MetricsMixin, OP_CONTAINS, OP_INSERT, OP_NOP,
                                OP_REMOVE, SetSpec)
 from repro.core.nvm import hash32, np_hash32
+from repro.obs.metrics import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -445,7 +446,8 @@ def dispatch_batch(state: SetState, ops, keys, values, *, sspec: ShardSpec
         state, res, dropped = apply_batch(
             state, jnp.asarray(ops, jnp.int32), jnp.asarray(keys, jnp.int32),
             jnp.asarray(values, jnp.int32), sspec=sspec)
-        d = int(dropped)
+        with span("registry.sync.dropped"):
+            d = int(dropped)
         mask = np_v1_drop_mask(
             keys, n_shards=sspec.n_shards,
             lane_budget=sspec.lane_budget(b)) if d else np.zeros((b,), bool)
@@ -464,7 +466,8 @@ def dispatch_get(state: SetState, keys, *, sspec: ShardSpec,
         state, vals, present, dropped = get(
             state, jnp.asarray(keys, jnp.int32), sspec=sspec,
             default=default)
-        d = int(dropped)
+        with span("registry.sync.dropped"):
+            d = int(dropped)
         mask = np_v1_drop_mask(
             keys, n_shards=sspec.n_shards,
             lane_budget=sspec.lane_budget(b)) if d else np.zeros((b,), bool)
@@ -511,11 +514,6 @@ def hybrid_recover(snap: SetState, persisted: jax.Array, keys: jax.Array,
     fn = functools.partial(E.hybrid_recover_impl, spec=sspec.shard_spec())
     return _dispatch(jax.vmap(fn), sspec)(snap, persisted, keys, values,
                                           stamp, delta_idx)
-
-
-def crash_and_recover(state: SetState, u: jax.Array, *, sspec: ShardSpec
-                      ) -> Tuple[SetState, jax.Array]:
-    return recover(*crash(state, u), sspec=sspec)
 
 
 # ---------------------------------------------------------------------------
@@ -675,7 +673,8 @@ class ShardedDurableMap(MetricsMixin):
         """True once ANY shard latched its index overflow (see
         ``DurableMap.overflowed``)."""
         self._dispatch_staged()
-        return bool(self.state.overflow.any())
+        with span("registry.sync.overflow"):
+            return bool(self.state.overflow.any())
 
     def _finish(self, res, dropped, drop_mask=None,
                 check_overflow: bool = True):
@@ -895,20 +894,26 @@ class ShardedDurableMap(MetricsMixin):
         the crash is applied -- exactly the crash-at-any-point semantics
         of the synchronous path.
         """
-        self._pre_crash()
-        if u is None:
-            u = np.random.default_rng(seed).random(
-                self.state.cur.shape).astype(np.float32)
-        t0 = time.perf_counter()
-        self.state, hist = crash_and_recover(self.state, jnp.asarray(u),
-                                             sspec=self.sspec)
-        self.last_recovery_hist_shards = np.asarray(hist)
-        self.last_recovery_hist = self.last_recovery_hist_shards.sum(axis=0)
-        jax.block_until_ready(self.state.keys)    # honest recovery timing
-        self.last_recovery_seconds = time.perf_counter() - t0
-        self._metrics_post_recovery(
-            scanned_slots=self.n_shards * self.spec.capacity)
-        self._post_recovery_overflow()    # latch recomputed; warning re-armed
+        with span("registry.recover"):
+            self._pre_crash()
+            if u is None:
+                u = np.random.default_rng(seed).random(
+                    self.state.cur.shape).astype(np.float32)
+            t0 = time.perf_counter()
+            with span("registry.crash"):
+                crashed = crash(self.state, jnp.asarray(u))
+            with span("registry.rebuild"):
+                self.state, hist = recover(*crashed, sspec=self.sspec)
+            with span("registry.sync.recover_hist"):
+                self.last_recovery_hist_shards = np.asarray(hist)
+            self.last_recovery_hist = self.last_recovery_hist_shards.sum(
+                axis=0)
+            with span("registry.sync.recover_ready"):
+                jax.block_until_ready(self.state.keys)  # honest timing
+            self.last_recovery_seconds = time.perf_counter() - t0
+            self._metrics_post_recovery(
+                scanned_slots=self.n_shards * self.spec.capacity)
+            self._post_recovery_overflow()  # latch recomputed; re-armed
         return self
 
     # --- snapshot + delta-log hybrid recovery (DESIGN.md §11) -----------

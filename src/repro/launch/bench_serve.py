@@ -46,7 +46,7 @@ from repro.core import (DurableMap, DurableQueue, QueueSpec,
 from repro.core import queue as Q
 from repro.core.engine import OP_CONTAINS, OP_INSERT, OP_NOP, OP_REMOVE
 from repro.launch.compile_cache import use_compile_cache
-from repro.obs import JSONLSink, MetricsRegistry, bench_meta
+from repro.obs import JSONLSink, MetricsRegistry, bench_meta, span
 
 
 @dataclasses.dataclass
@@ -165,26 +165,32 @@ def _spine_round(m: MetricsRegistry, registry, req_q, resp_q, spec_q,
     AFTER the full round is forced -- the completion instant."""
     active = jnp.asarray(ops != OP_NOP)
     jkeys = jnp.asarray(keys)
-    with m.span("ack"):
+    with m.span("spine.ack"), span("queue.enqueue"):
         req_q.state, ok_in, _ = _enqueue_masked(
             req_q.state, jkeys, active, spec=spec_q)
-    with m.span("dispatch"):
+    with m.span("spine.dispatch"):
         # volatile peek is implicit (the batch IS in hand); the mixed
         # registry batch does route (host stage 1) + device dispatch
         res = registry.apply(ops, keys, keys)
-    with m.span("commit"):
+    with m.span("spine.commit"):
         # completion durable BEFORE the request dequeue commit
-        resp_q.state, _, _ = _enqueue_masked(
-            resp_q.state, jkeys, active, spec=spec_q)
-        req_q.state, _, ok_c, _ = Q.dequeue(req_q.state, active,
-                                            spec=spec_q)
-        resp_q.state, _, ok_d, _ = Q.dequeue(resp_q.state, active,
-                                             spec=spec_q)   # delivery
-    with m.span("force"):
+        with span("queue.enqueue"):
+            resp_q.state, _, _ = _enqueue_masked(
+                resp_q.state, jkeys, active, spec=spec_q)
+        with span("queue.dequeue"):
+            req_q.state, _, ok_c, _ = Q.dequeue(req_q.state, active,
+                                                spec=spec_q)
+        with span("queue.dequeue"):
+            resp_q.state, _, ok_d, _ = Q.dequeue(resp_q.state, active,
+                                                 spec=spec_q)  # delivery
+    with m.span("spine.force"):
         np.asarray(res)                       # force registry results
-        n_acked = int(np.asarray(ok_in).sum())
-        n_committed = int(np.asarray(ok_c).sum())
-        n_delivered = int(np.asarray(ok_d).sum())
+        with span("queue.sync.ok"):
+            n_acked = int(np.asarray(ok_in).sum())
+        with span("queue.sync.ok"):
+            n_committed = int(np.asarray(ok_c).sum())
+        with span("queue.sync.ok"):
+            n_delivered = int(np.asarray(ok_d).sum())
     n_real = int((ops != OP_NOP).sum())
     if n_acked < n_real:
         m.counter("spine.ack_rejected").inc(n_real - n_acked)

@@ -6,7 +6,8 @@ One registry, four primitives, a pluggable sink protocol:
   Gauge            last-written level (backlog depth, lane budget)
   Histogram        log2-bucketed distribution with EXACT sample-based
                    p50/p99/p999 (per-request latency, span durations)
-  span(name)       context-manager timer recording into a histogram
+  span(name)       a span on the profiler's clock (``TraceAnnotation``),
+                   recording into a histogram when given a registry
 
 Everything accumulates HOST-SIDE only: nothing in this package is ever
 traced into a jit program, and device counters (``n_psync``/``n_ops``
@@ -23,9 +24,9 @@ receive whole snapshots via :meth:`MetricsRegistry.emit`.
 from repro.obs.bridge import DeviceCounterBridge
 from repro.obs.meta import bench_meta
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
-                               Span)
+                               span)
 from repro.obs.sinks import InMemorySink, JSONLSink, Sink
 
 __all__ = ["Counter", "DeviceCounterBridge", "Gauge", "Histogram",
-           "MetricsRegistry", "Span", "InMemorySink", "JSONLSink", "Sink",
+           "MetricsRegistry", "span", "InMemorySink", "JSONLSink", "Sink",
            "bench_meta"]

@@ -4,8 +4,9 @@ Design constraints, in order:
 
   1. Near-zero hot-path cost.  ``Counter.inc`` is one int add;
      ``Histogram.record_many`` appends ONE numpy array reference per
-     call (no copies, no sorting); spans are two ``perf_counter_ns``
-     reads.  Nothing allocates per sample.
+     call (no copies, no sorting) in O(1); a span is one profiler
+     ``TraceAnnotation`` enter/exit, plus two ``perf_counter_ns`` reads
+     when it also records into a registry.
   2. Never inside jit.  These objects are plain host Python; structures
      that carry device-resident counters expose them through registry
      *collectors* that are only invoked at snapshot time -- an explicit
@@ -23,6 +24,7 @@ from __future__ import annotations
 import time
 from typing import Callable, Dict, Optional
 
+import jax
 import numpy as np
 
 # log2 bucket i counts samples in [2^i, 2^(i+1)) * RESOLUTION seconds;
@@ -64,8 +66,8 @@ class Histogram:
     uniformly subsampled 2x (repeatedly as needed) and quantiles become
     estimates -- flagged via ``exact`` in the snapshot.
     """
-    __slots__ = ("_chunks", "_n", "_sum", "_min", "_max", "_stride",
-                 "max_samples")
+    __slots__ = ("_chunks", "_retained", "_n", "_sum", "_min", "_max",
+                 "_stride", "max_samples")
 
     def __init__(self, max_samples: int = 1 << 25):
         self.max_samples = max_samples
@@ -73,6 +75,7 @@ class Histogram:
 
     def reset(self) -> None:
         self._chunks = []
+        self._retained = 0   # sum of the chunks' sizes
         self._n = 0          # recorded sample count (pre-subsampling)
         self._sum = 0.0
         self._min = None
@@ -91,12 +94,14 @@ class Histogram:
         lo, hi = float(values.min()), float(values.max())
         self._min = lo if self._min is None else min(self._min, lo)
         self._max = hi if self._max is None else max(self._max, hi)
-        self._chunks.append(values[::self._stride]
-                            if self._stride > 1 else values)
-        if sum(c.size for c in self._chunks) > self.max_samples:
+        kept = values[::self._stride] if self._stride > 1 else values
+        self._chunks.append(kept)
+        self._retained += kept.size
+        if self._retained > self.max_samples:
             # halve retention uniformly; min/max/sum/count stay exact
             self._stride *= 2
             self._chunks = [np.concatenate(self._chunks)[::2]]
+            self._retained = self._chunks[0].size
 
     @property
     def count(self) -> int:
@@ -149,22 +154,37 @@ class Histogram:
         return d
 
 
-class Span:
-    """Context-manager stage timer: records elapsed seconds into its
-    histogram on exit.  Two clock reads; reentrant-safe (each ``with``
-    gets its own instance via :meth:`MetricsRegistry.span`)."""
-    __slots__ = ("_hist", "_t0")
+def span(name: str, registry: Optional["MetricsRegistry"] = None):
+    """``with span("registry.route"): ...`` -- a span on the profiler's
+    clock: a ``jax.profiler.TraceAnnotation``, so a traced run holds it
+    in the same ``.xplane.pb`` as the device ops.  With ``registry`` the
+    span also records its seconds into that registry's ``span.<name>``
+    histogram.  Without the profiler running, a span costs one
+    annotation enter/exit.  Names are ``<layer>.<step>``; a
+    ``<layer>.sync.<what>`` span wraps exactly one device-to-host read
+    (DESIGN.md §10)."""
+    if registry is None:
+        return jax.profiler.TraceAnnotation(name)
+    return _Timed(jax.profiler.TraceAnnotation(name),
+                  registry.histogram(f"span.{name}"))
 
-    def __init__(self, hist: Histogram):
+
+class _Timed:
+    """A span that also records its duration into a histogram."""
+    __slots__ = ("_ann", "_hist", "_t0")
+
+    def __init__(self, ann, hist: Histogram):
+        self._ann = ann
         self._hist = hist
-        self._t0 = None
 
-    def __enter__(self) -> "Span":
+    def __enter__(self) -> "_Timed":
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> None:
         self._hist.record((time.perf_counter_ns() - self._t0) * 1e-9)
+        self._ann.__exit__(*exc)
 
 
 class MetricsRegistry:
@@ -208,10 +228,10 @@ class MetricsRegistry:
                    else {"max_samples": max_samples}))
         return h
 
-    def span(self, name: str) -> Span:
-        """``with registry.span("route"): ...`` -- stage timer into the
-        ``span.<name>`` histogram."""
-        return Span(self.histogram(f"span.{name}"))
+    def span(self, name: str):
+        """``with registry.span("spine.ack"): ...`` -- :func:`span`
+        recording into this registry's ``span.<name>`` histogram."""
+        return span(name, self)
 
     def register_collector(self, name: str,
                            fn: Callable[[], dict]) -> None:

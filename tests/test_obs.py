@@ -98,6 +98,33 @@ def test_histogram_subsampling_degrades_gracefully():
     assert snap["p50"] == pytest.approx(np.percentile(vals, 50), rel=0.05)
 
 
+@pytest.mark.parametrize("max_samples", [1 << 25, 1000])
+def test_histogram_keeps_a_running_retained_count(max_samples):
+    # one record at a time, as a span records: the retained count is kept
+    # as it goes (O(1) per record), never re-summed over the chunks
+    rng = np.random.default_rng(5)
+    vals = rng.lognormal(mean=-7, sigma=1.0, size=10_000)
+    h = Histogram(max_samples=max_samples)
+    for v in vals:
+        h.record(v)
+    assert h._retained == sum(c.size for c in h._chunks)
+    assert h._retained <= max_samples
+    snap = h.snapshot()
+    assert snap["count"] == vals.size
+    assert snap["sum"] == pytest.approx(vals.sum(), rel=1e-12)
+    assert snap["min"] == vals.min() and snap["max"] == vals.max()
+    if max_samples > vals.size:
+        assert snap["exact"] is True
+        for q in (50, 99, 99.9):
+            assert h.percentile(q) == np.percentile(vals, q,
+                                                    method="nearest")
+    else:
+        assert snap["exact"] is False
+        assert snap["p50"] == pytest.approx(np.percentile(vals, 50),
+                                            rel=0.1)
+    assert h._retained == sum(c.size for c in h._chunks)
+
+
 def test_empty_histogram_snapshot():
     snap = Histogram().snapshot()
     assert snap["count"] == 0
