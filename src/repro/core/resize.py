@@ -366,7 +366,7 @@ class ElasticShardedMap(MetricsMixin):
         m.state, fl = RT.dispatch_plan(m.state, plan, sspec=m.sspec,
                                        kind="get", default=default)
         vals, _, dropped, drop_mask = fl.force()
-        m._finish(vals, dropped, drop_mask)
+        m._finish(vals, dropped, drop_mask, fl)
         return vals
 
     def get(self, keys, default: int = 0):

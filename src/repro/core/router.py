@@ -479,6 +479,22 @@ def _group_dispatch(group_fn, state, lanes, *, sspec, groups: int):
     return (state,) + tuple(out[1:])
 
 
+def _packed_row(st, lanes, codes, dropped) -> jax.Array:
+    """One group's row of the packed output: ``lanes`` (values, for
+    ``get``), the (Bd,) lane codes ``result | kept << 1``, the group's
+    dropped count, and the group's overflow latch AFTER this batch
+    (``any`` over its local shards).  The latch is reduced per group, so
+    under ``shard_map`` the row stays on its device: the host ORs the D
+    latches and no collective enters the program."""
+    tail = jnp.stack([dropped.astype(jnp.int32),
+                      jnp.any(st.overflow).astype(jnp.int32)])
+    return jnp.concatenate(lanes + (codes, tail))
+
+
+def _codes(res: jax.Array, kept: jax.Array) -> jax.Array:
+    return res.astype(jnp.int32) | (kept.astype(jnp.int32) << 1)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("sspec", "groups", "lane_budget"),
                    donate_argnums=(0,))
@@ -486,11 +502,12 @@ def _apply_v2(state, d_ops: jax.Array, d_keys: jax.Array,
               d_vals: jax.Array, *, sspec, groups: int, lane_budget: int):
     """Device-local mixed-op dispatch: per device, stage-2 route the (Bd,)
     sub-batch into the (S/D, L) local grid and execute the local shards
-    in one vmapped ``apply_batch_impl``.  Returns (stacked state,
-    (D, Bd) results, (D,) per-device dropped counts, (D, Bd) per-lane
-    kept mask -- False exactly for the real lanes stage 2 dropped past a
+    in one vmapped ``apply_batch_impl``.  Returns (stacked state, packed
+    i32[D, Bd + 2]): per group, the lane codes ``result | kept << 1``
+    (kept is False exactly for the real lanes stage 2 dropped past a
     ``max_lane_budget`` cap, so callers can retry/reshard instead of
-    reading a dropped lane as a successful no-op)."""
+    reading a dropped lane as a successful no-op), the dropped count and
+    the overflow latch -- everything the host needs, in ONE read."""
     spec = sspec.shard_spec()
 
     def group_fn(st, o, k, v):
@@ -499,7 +516,8 @@ def _apply_v2(state, d_ops: jax.Array, d_keys: jax.Array,
         fn = functools.partial(E.apply_batch_impl, spec=spec)
         st, r_res = jax.vmap(fn)(st, r_ops, r_keys, r_vals)
         kept = (slot >= 0) | (o == OP_NOP)
-        return st, _grid_gather(r_res, slot, False), dropped, kept
+        return st, _packed_row(
+            st, (), _codes(_grid_gather(r_res, slot, False), kept), dropped)
 
     return _group_dispatch(group_fn, state,
                            (d_ops, d_keys, d_vals), sspec=sspec,
@@ -512,7 +530,9 @@ def _apply_v2(state, d_ops: jax.Array, d_keys: jax.Array,
                    donate_argnums=(0,))
 def _get_v2(state, d_keys: jax.Array, d_active: jax.Array, *, sspec,
             groups: int, lane_budget: int, default: int = 0):
-    """Device-local value lookup; same routing as :func:`_apply_v2`."""
+    """Device-local value lookup; same routing as :func:`_apply_v2`.
+    Returns (stacked state, packed i32[D, 2*Bd + 2]): per group the (Bd,)
+    values, then the codes ``present | kept << 1``, dropped, overflow."""
     spec = sspec.shard_spec()
 
     def group_fn(st, k, act):
@@ -527,7 +547,8 @@ def _get_v2(state, d_keys: jax.Array, d_active: jax.Array, *, sspec,
         vals = _grid_gather(r_vals, slot, jnp.int32(default))
         pres = _grid_gather(r_pres, slot, False)
         kept = (slot >= 0) | ~act
-        return st, vals, pres, dropped, kept
+        return st, _packed_row(st, (vals.astype(jnp.int32),),
+                               _codes(pres, kept), dropped)
 
     return _group_dispatch(group_fn, state, (d_keys, d_active),
                            sspec=sspec, groups=groups)
@@ -546,28 +567,35 @@ def _get_v2(state, d_keys: jax.Array, d_active: jax.Array, *, sspec,
 # ---------------------------------------------------------------------------
 
 
+_KEPT = np.int32(2)        # lane-code bit: the lane was not dropped
+
+
 class InFlight:
     """A dispatched-but-unforced v2 batch.
 
-    Holds the device futures of the jitted stage-2 program plus the
-    stage-1 :class:`RoutePlan` needed to invert them.  ``force()``
-    performs the host syncs (one read per output, each inside its
-    ``registry.sync.*`` span), returns the per-lane numpy results, and
-    recycles the plan's scratch set.  ``kind`` is "apply"
-    (``force() -> (results bool[B], dropped, drop_mask bool[B])``) or
-    "get" (``force() -> (values i32[B], present bool[B], dropped,
-    drop_mask bool[B])``).  ``drop_mask[i]`` is True exactly when real
-    lane i was shed past a ``max_lane_budget`` cap -- its result is NOT
-    a successful no-op and the caller must retry or reshard (all-False
-    on every drop-free trace; OP_NOP padding is never "dropped").
+    Holds the packed device output of the jitted stage-2 program plus the
+    stage-1 :class:`RoutePlan` needed to invert it.  ``force()`` performs
+    the batch's one host sync (a single read inside its
+    ``registry.sync.batch`` span), returns the per-lane numpy results,
+    sets ``overflow``, and recycles the plan's scratch set.  ``kind`` is
+    "apply" (``force() -> (results bool[B], dropped, drop_mask
+    bool[B])``) or "get" (``force() -> (values i32[B], present bool[B],
+    dropped, drop_mask bool[B])``).  ``drop_mask[i]`` is True exactly
+    when real lane i was shed past a ``max_lane_budget`` cap -- its
+    result is NOT a successful no-op and the caller must retry or
+    reshard (all-False on every drop-free trace; OP_NOP padding is never
+    "dropped").  ``overflow`` is the map's overflow latch after this
+    batch (any shard), known once forced; None for an empty batch, which
+    ran no program.
     """
-    __slots__ = ("kind", "plan", "outs", "default", "_forced")
+    __slots__ = ("kind", "plan", "outs", "default", "overflow", "_forced")
 
     def __init__(self, kind: str, plan: RoutePlan, outs, default: int = 0):
         self.kind = kind
         self.plan = plan
-        self.outs = outs          # device futures, or None for empty plans
+        self.outs = outs          # packed device future, or None if empty
         self.default = default
+        self.overflow = None
         self._forced = None
 
     @property
@@ -583,32 +611,25 @@ class InFlight:
         return self._forced
 
     def _gather(self):
-        slot = self.plan.slot
-        if self.kind == "apply":
-            if self.outs is None:
-                return np.zeros((0,), bool), 0, np.zeros((0,), bool)
-            res, dropped, kept = self.outs
-            return (host_gather(_read("registry.sync.results", res), slot,
-                                False),
-                    int(_read("registry.sync.dropped", dropped).sum()),
-                    ~host_gather(_read("registry.sync.kept", kept), slot,
-                                 True))
         if self.outs is None:
-            return (np.zeros((0,), np.int32), np.zeros((0,), bool), 0,
-                    np.zeros((0,), bool))
-        vals, pres, dropped, kept = self.outs
-        return (host_gather(_read("registry.sync.values", vals), slot,
-                            np.int32(self.default)),
-                host_gather(_read("registry.sync.present", pres), slot,
-                            False),
-                int(_read("registry.sync.dropped", dropped).sum()),
-                ~host_gather(_read("registry.sync.kept", kept), slot, True))
-
-
-def _read(name: str, x) -> np.ndarray:
-    """One device-to-host read, inside its ``registry.sync.*`` span."""
-    with span(name):
-        return np.asarray(x)
+            empty = np.zeros((0,), bool)
+            if self.kind == "apply":
+                return empty, 0, empty
+            return np.zeros((0,), np.int32), empty, 0, empty
+        with span("registry.sync.batch"):
+            packed = np.asarray(self.outs)
+        width = packed.shape[1] - 2
+        bd = width if self.kind == "apply" else width // 2
+        slot = self.plan.slot
+        dropped = int(packed[:, -2].sum())
+        self.overflow = bool(packed[:, -1].any())
+        # untransported OP_NOP lanes read as result False, kept
+        codes = host_gather(packed[:, width - bd:width], slot, _KEPT)
+        hit, drop_mask = (codes & 1).astype(bool), (codes & _KEPT) == 0
+        if self.kind == "apply":
+            return hit, dropped, drop_mask
+        return (host_gather(packed[:, :bd], slot, np.int32(self.default)),
+                hit, dropped, drop_mask)
 
 
 def dispatch_plan(state, plan: RoutePlan, *, sspec, kind: str = "apply",
@@ -622,18 +643,17 @@ def dispatch_plan(state, plan: RoutePlan, *, sspec, kind: str = "apply",
                                default)
     with span("registry.launch"):
         if kind == "apply":
-            state, res, dropped, kept = _apply_v2(
+            state, packed = _apply_v2(
                 state, jnp.asarray(plan.d_ops), jnp.asarray(plan.d_keys),
                 jnp.asarray(plan.d_vals), sspec=sspec, groups=plan.groups,
                 lane_budget=plan.lane_budget)
-            return state, InFlight(kind, plan, (res, dropped, kept))
-        state, vals, pres, dropped, kept = _get_v2(
-            state, jnp.asarray(plan.d_keys),
-            jnp.asarray(plan.d_ops) == OP_CONTAINS, sspec=sspec,
-            groups=plan.groups, lane_budget=plan.lane_budget,
-            default=default)
-        return state, InFlight(kind, plan, (vals, pres, dropped, kept),
-                               default)
+        else:
+            state, packed = _get_v2(
+                state, jnp.asarray(plan.d_keys),
+                jnp.asarray(plan.d_ops) == OP_CONTAINS, sspec=sspec,
+                groups=plan.groups, lane_budget=plan.lane_budget,
+                default=default)
+        return state, InFlight(kind, plan, packed, default)
 
 
 def apply_batch_v2_async(state, ops, keys, values, *, sspec):
@@ -655,20 +675,22 @@ def get_v2_async(state, keys, *, sspec, default: int = 0):
 
 def apply_batch_v2(state, ops, keys, values, *, sspec):
     """Two-stage routed mixed-op batch.  Returns ``(state, results
-    bool[B] (numpy), dropped int, drop_mask bool[B], plan RoutePlan)``.
-    Linearization and psync accounting are bit-identical to the v1
-    single-stage router (same lanes, same per-shard order)."""
+    bool[B] (numpy), dropped int, drop_mask bool[B], the forced
+    InFlight)`` -- its ``plan`` and ``overflow`` latch.  Linearization
+    and psync accounting are bit-identical to the v1 single-stage router
+    (same lanes, same per-shard order)."""
     state, fl = apply_batch_v2_async(state, ops, keys, values, sspec=sspec)
     out, dropped, drop_mask = fl.force()
-    return state, out, dropped, drop_mask, fl.plan
+    return state, out, dropped, drop_mask, fl
 
 
 def get_v2(state, keys, *, sspec, default: int = 0):
     """Two-stage routed value lookup.  Returns ``(state, values i32[B],
-    present bool[B], dropped int, drop_mask bool[B], plan)``."""
+    present bool[B], dropped int, drop_mask bool[B], the forced
+    InFlight)``."""
     state, fl = get_v2_async(state, keys, sspec=sspec, default=default)
     out_v, out_p, dropped, drop_mask = fl.force()
-    return state, out_v, out_p, dropped, drop_mask, fl.plan
+    return state, out_v, out_p, dropped, drop_mask, fl
 
 
 def precompile(state, batch: int, *, sspec, partial=None):
@@ -712,9 +734,9 @@ def precompile(state, batch: int, *, sspec, partial=None):
         nop = jnp.full((d, bd), OP_NOP, jnp.int32)
         zero = jnp.zeros((d, bd), jnp.int32)
         for lane in bds[bd]:
-            state, _, _, _ = _apply_v2(state, nop, zero, zero, sspec=sspec,
-                                       groups=d, lane_budget=lane)
-            state, _, _, _, _ = _get_v2(state, zero, nop == OP_CONTAINS,
-                                        sspec=sspec, groups=d,
-                                        lane_budget=lane, default=0)
+            state, _ = _apply_v2(state, nop, zero, zero, sspec=sspec,
+                                 groups=d, lane_budget=lane)
+            state, _ = _get_v2(state, zero, nop == OP_CONTAINS,
+                               sspec=sspec, groups=d, lane_budget=lane,
+                               default=0)
     return state, budgets

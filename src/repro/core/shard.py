@@ -431,12 +431,14 @@ def get(state: SetState, keys: jax.Array, *, sspec: ShardSpec,
 
 def dispatch_batch(state: SetState, ops, keys, values, *, sspec: ShardSpec
                    ) -> Tuple[SetState, jax.Array, int, np.ndarray,
-                              Optional[RT.RoutePlan]]:
+                              Optional[RT.InFlight]]:
     """Route + execute a mixed batch through the spec's router.  Returns
     ``(state, per-lane results, dropped count, per-lane drop mask,
-    stage-1 plan-or-None)``.  ``drop_mask[i]`` is True exactly when lane
-    i was shed past the lane budget -- its result is NOT a successful
-    no-op; callers retry or reshard (all-False on drop-free traces).
+    forced v2 batch-or-None)`` -- the batch carries its stage-1 ``plan``
+    and the ``overflow`` latch its one read brought back.
+    ``drop_mask[i]`` is True exactly when lane i was shed past the lane
+    budget -- its result is NOT a successful no-op; callers retry or
+    reshard (all-False on drop-free traces).
     The v2 path runs stage 1 host-side (no all-gather under shard_map)
     and picks the adaptive lane budget; v1 is the single-stage global
     router.  Results/state/psyncs are bit-identical between the two
@@ -452,15 +454,13 @@ def dispatch_batch(state: SetState, ops, keys, values, *, sspec: ShardSpec
             keys, n_shards=sspec.n_shards,
             lane_budget=sspec.lane_budget(b)) if d else np.zeros((b,), bool)
         return state, res, d, mask, None
-    state, res, dropped, drop_mask, plan = RT.apply_batch_v2(
-        state, ops, keys, values, sspec=sspec)
-    return state, res, dropped, drop_mask, plan
+    return RT.apply_batch_v2(state, ops, keys, values, sspec=sspec)
 
 
 def dispatch_get(state: SetState, keys, *, sspec: ShardSpec,
                  default: int = 0):
     """Value lookup through the spec's router; returns ``(state, values,
-    present, dropped, drop_mask, plan-or-None)``."""
+    present, dropped, drop_mask, forced v2 batch-or-None)``."""
     if sspec.router == "v1":
         b = np.asarray(keys).shape[0]
         state, vals, present, dropped = get(
@@ -676,8 +676,7 @@ class ShardedDurableMap(MetricsMixin):
         with span("registry.sync.overflow"):
             return bool(self.state.overflow.any())
 
-    def _finish(self, res, dropped, drop_mask=None,
-                check_overflow: bool = True):
+    def _finish(self, res, dropped, drop_mask=None, forced=None):
         if drop_mask is not None:
             self.last_drop_mask = drop_mask
         d = int(dropped)
@@ -693,10 +692,13 @@ class ShardedDurableMap(MetricsMixin):
                     f"received more than the lane budget; {knob} "
                     f"or submit smaller batches (sspec={self.sspec})",
                     stacklevel=4)
-        # the overflow latch lives in device state; checking it forces a
-        # sync on EVERY dispatched batch, so the pipelined path defers it
-        # to pipeline_flush() instead of checking per forced batch
-        if check_overflow and not self._overflow_warned and self.overflowed:
+        # the overflow latch lives in device state.  A forced v2 batch
+        # (``forced``, an InFlight) brought it back in its one packed
+        # read; an empty one ran no program, so its latch (None) cannot
+        # have moved.  Without one (router v1, a recheck) reading the
+        # latch is a sync of its own
+        if not self._overflow_warned and (
+                self.overflowed if forced is None else bool(forced.overflow)):
             self._overflow_warned = True
             E.warn_structure(self._overflow_message(), stacklevel=4)
         return res
@@ -753,8 +755,7 @@ class ShardedDurableMap(MetricsMixin):
             h._value, h._dropped, h._drop_mask = out
         else:
             h._value, h._present, h._dropped, h._drop_mask = out
-        self._finish(h._value, h._dropped, h._drop_mask,
-                     check_overflow=False)
+        self._finish(h._value, h._dropped, h._drop_mask, h._inflight)
 
     def _force_through(self, handle):
         """Force the pipeline, in submit order, through ``handle``."""
@@ -765,12 +766,12 @@ class ShardedDurableMap(MetricsMixin):
             self._force_oldest()
 
     def pipeline_flush(self):
-        """Dispatch the staged batch, force every pending batch, and run
-        the deferred overflow check.  The no-op on a synchronous map."""
+        """Dispatch the staged batch and force every pending batch (each
+        brings its overflow latch to ``_finish``).  The no-op on a
+        synchronous map."""
         self._dispatch_staged()
         while self._pending:
             self._force_oldest()
-        self._finish(None, 0)                 # deferred overflow check
         return self
 
     def scratch_stats(self) -> dict:
@@ -785,8 +786,7 @@ class ShardedDurableMap(MetricsMixin):
         return RT.scratch_stats()
 
     def _recheck_overflow(self):
-        # the sharded overflow check lives in _finish (it also services
-        # the deferred pipelined-path check)
+        # the sharded overflow check lives in _finish
         self._finish(None, 0)
 
     def _metrics_extra(self) -> dict:
@@ -808,11 +808,11 @@ class ShardedDurableMap(MetricsMixin):
     def _apply(self, ops, keys, values):
         if self.sspec.pipeline_depth > 1:
             return self._submit("apply", ops, keys, values)
-        self.state, res, dropped, drop_mask, plan = dispatch_batch(
+        self.state, res, dropped, drop_mask, fl = dispatch_batch(
             self.state, ops, keys, values, sspec=self.sspec)
-        if plan is not None:
-            self.last_route = plan
-        return self._finish(res, dropped, drop_mask)
+        if fl is not None:
+            self.last_route = fl.plan
+        return self._finish(res, dropped, drop_mask, fl)
 
     def insert(self, keys, values=None):
         keys = np.asarray(keys, np.int32)
@@ -834,12 +834,12 @@ class ShardedDurableMap(MetricsMixin):
         """Values for present keys, ``default`` otherwise."""
         if self.sspec.pipeline_depth > 1:
             return self._submit("get", None, keys, None, default)
-        self.state, vals, _, dropped, drop_mask, plan = dispatch_get(
+        self.state, vals, _, dropped, drop_mask, fl = dispatch_get(
             self.state, np.asarray(keys, np.int32), sspec=self.sspec,
             default=default)
-        if plan is not None:
-            self.last_route = plan
-        return self._finish(vals, dropped, drop_mask)
+        if fl is not None:
+            self.last_route = fl.plan
+        return self._finish(vals, dropped, drop_mask, fl)
 
     def apply(self, ops, keys, values=None):
         """Mixed contains/insert/remove batch; see :func:`apply_batch`."""

@@ -161,19 +161,13 @@ def run_router_v2_adversary_property(mode, ops, placement, groups, cap, u,
     def oracle_for(key):
         return oracles[int(np_shard_of(np.array([key]), _N_SHARDS)[0])]
 
-    n_success = 0
-    for i in range(0, len(ops), _BATCH):
-        chunk = ops[i:i + _BATCH]
-        codes = np.full(_BATCH, OP_NOP, np.int32)
-        keys = np.zeros(_BATCH, np.int32)
-        for j, (kind, key) in enumerate(chunk):
-            codes[j], keys[j] = _OP_CODE[kind], key
-        # the routing drop rule: per shard ROW, the first-L real lanes in
-        # batch order are kept (L == the realized adaptive budget)
-        kept = np.ones(_BATCH, bool)
+    def kept_lanes(codes, keys):
+        """The routing drop rule: per shard ROW, the first-L real lanes in
+        batch order are kept (L == the realized adaptive budget)."""
+        kept = np.ones(keys.size, bool)
         if cap:
             budget = RT.adaptive_lane_budget(
-                m.sspec, _BATCH,
+                m.sspec, keys.size,
                 int(np.bincount(rows_of(keys)[codes != OP_NOP],
                                 minlength=_N_SHARDS).max()))
             taken = {}
@@ -182,7 +176,18 @@ def run_router_v2_adversary_property(mode, ops, placement, groups, cap, u,
                     continue
                 taken[r] = taken.get(r, 0) + 1
                 kept[j] = taken[r] <= budget
+        return kept
+
+    n_success = 0
+    for i in range(0, len(ops), _BATCH):
+        chunk = ops[i:i + _BATCH]
+        codes = np.full(_BATCH, OP_NOP, np.int32)
+        keys = np.zeros(_BATCH, np.int32)
+        for j, (kind, key) in enumerate(chunk):
+            codes[j], keys[j] = _OP_CODE[kind], key
+        kept = kept_lanes(codes, keys)
         got = np.array(m.apply(codes, keys, keys * 10))
+        np.testing.assert_array_equal(m.last_drop_mask, ~kept)
         exp = np.zeros(_BATCH, bool)
         for phase in ("contains", "insert", "remove"):  # phase linearization
             for j, (kind, key) in enumerate(chunk):
@@ -207,7 +212,17 @@ def run_router_v2_adversary_property(mode, ops, placement, groups, cap, u,
     # the rebuilt state starts a fresh counter: recovery itself must issue
     # ZERO psyncs (payloads are already durable, engine.recover docstring)
     assert m.psyncs == 0, "recovery must issue no psync"
-    got = np.array(m.contains(np.arange(8)))
+    # reads shed past the cap come back False by contract (drop_mask: the
+    # caller must retry): re-read exactly the shed lanes, one batch at a
+    # time, until none is shed -- then every key is checked
+    got = np.zeros(8, bool)
+    todo = np.arange(8, dtype=np.int32)
+    while todo.size:
+        read = np.array(m.contains(todo))
+        kept = kept_lanes(np.full(todo.size, OP_CONTAINS, np.int32), todo)
+        np.testing.assert_array_equal(m.last_drop_mask, ~kept)
+        got[todo[kept]] = read[kept]
+        todo = todo[~kept]
     for key in range(8):
         assert got[key] == (key in oracle_for(key).index), (key, mode)
 

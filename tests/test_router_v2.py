@@ -16,7 +16,13 @@ Pins the three guarantees of the two-stage device-local router:
      dropped == lanes over budget, dropped lanes return False with zero
      side effects (state bit-equal to applying only the kept lanes), and
      the one-shot RuntimeWarning fires exactly once.
+  4. ONE READ PER BATCH -- the stage-2 program packs results, kept mask,
+     dropped count and the per-group overflow latch into one array, so a
+     synchronous batch makes exactly one device-to-host read and
+     ``force()`` unpacks the same tuples as the v1 router and the drop
+     rule give; the latch is reduced per group and ORed on the host.
 """
+import contextlib
 import os
 import subprocess
 import sys
@@ -365,3 +371,214 @@ def test_shard_map_program_has_no_collectives():
                        capture_output=True, text=True, timeout=900)
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
     assert "NO_COLLECTIVE OK" in r.stdout
+
+
+# ---------------------------------------------------------------------------
+# 5. One device-to-host read per batch: the packed stage-2 output.
+# ---------------------------------------------------------------------------
+
+
+class _Spans:
+    """Stands in for ``jax.profiler.TraceAnnotation``, which every
+    ``repro.obs.span`` opens: keeps the name of each span opened."""
+
+    def __init__(self):
+        self.names = []
+
+    def __call__(self, name):
+        self.names.append(name)
+        return contextlib.nullcontext()
+
+    def syncs(self):
+        return [n for n in self.names if n.startswith("registry.sync.")]
+
+
+@pytest.fixture
+def spans(monkeypatch):
+    rec = _Spans()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", rec)
+    return rec
+
+
+def _keys_on_row(sspec, row, n):
+    """``n`` distinct keys whose storage row is ``row``."""
+    cand = np.arange(1, 1 << 14, dtype=np.int32)
+    got = cand[RT._np_row_of(cand, sspec, RT.resolve_groups(sspec)) == row]
+    return got[:n]
+
+
+@pytest.mark.parametrize("cap", (0, 2))
+@pytest.mark.parametrize("groups", (1, 2, 4))
+@pytest.mark.parametrize("backend", ("bucket", "probe"))
+def test_packed_read_matches_v1_and_kept_mask(backend, groups, cap, spans):
+    """``force()`` unpacks the one packed read into the same tuples as
+    before: results equal the v1 router's on the kept lanes, the drop
+    mask is exactly the cap's shed set, and every synchronous batch --
+    forced by hand or through the façade, apply or get -- opens exactly
+    one registry sync span (no overflow read while none has latched)."""
+    base = SetSpec(capacity=512, backend=backend)
+    kw = dict(max_lane_budget=cap, min_lane_budget=1) if cap else {}
+    m = ShardedDurableMap(base, n_shards=8, n_device_groups=groups, **kw)
+    v1 = ShardedDurableMap(base, n_shards=8, router="v1")
+    assert RT.resolve_groups(m.sspec) == groups
+    rng = np.random.default_rng(3 * groups + cap)
+    shed = 0
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)   # the drop warning
+        for _ in range(3):
+            ops = rng.integers(0, 4, 32).astype(np.int32)  # incl. OP_NOP
+            keys = rng.integers(0, 64, 32).astype(np.int32)
+            plan = RT.host_route(m.sspec, ops, keys, keys * 3)
+            keep = _kept_mask(keys, ops, m.sspec, plan.lane_budget) \
+                | (ops == OP_NOP)                      # padding: kept
+            spans.names.clear()
+            m.state, fl = RT.dispatch_plan(m.state, plan, sspec=m.sspec)
+            res, dropped, drop_mask = fl.force()
+            assert spans.syncs() == ["registry.sync.batch"]
+            assert res.dtype == drop_mask.dtype == bool
+            np.testing.assert_array_equal(drop_mask, ~keep)
+            assert dropped == int((~keep).sum())
+            want = v1.apply(np.where(keep, ops, OP_NOP), keys, keys * 3)
+            np.testing.assert_array_equal(res, np.array(want))
+            assert fl.overflow is False
+            shed += dropped
+
+            ops = rng.integers(0, 3, 32).astype(np.int32)
+            keep = _kept_mask(keys, ops, m.sspec, adaptive_budget(m, keys))
+            spans.names.clear()
+            got = np.array(m.apply(ops, keys, keys * 5))
+            assert spans.syncs() == ["registry.sync.batch"]
+            assert not m._overflow_warned
+            np.testing.assert_array_equal(m.last_drop_mask, ~keep)
+            np.testing.assert_array_equal(
+                got, np.array(v1.apply(np.where(keep, ops, OP_NOP), keys,
+                                       keys * 5)))
+
+            spans.names.clear()
+            vals = np.array(m.get(keys, default=-1))
+            assert spans.syncs() == ["registry.sync.batch"]
+            keep = ~m.last_drop_mask
+            np.testing.assert_array_equal(
+                keep, _kept_mask(keys, np.full(32, OP_CONTAINS), m.sspec,
+                                 adaptive_budget(m, keys)))
+            np.testing.assert_array_equal(
+                vals, np.where(keep, np.array(v1.get(keys, default=-1)),
+                               -1))
+    assert (shed > 0) == bool(cap), "the cap must shed lanes, and only it"
+    assert v1.router_dropped == 0 and m.psyncs == v1.psyncs
+
+
+def adaptive_budget(m, keys):
+    """The lane budget stage 1 picks for an all-real batch of ``keys``."""
+    rows = RT._np_row_of(keys, m.sspec, RT.resolve_groups(m.sspec))
+    return RT.adaptive_lane_budget(m.sspec, keys.size,
+                                   int(np.bincount(rows).max()))
+
+
+@pytest.mark.parametrize("backend", ("bucket", "probe"))
+def test_packed_overflow_latch_and_one_shot_warning(backend, spans):
+    """``InFlight.overflow`` is the map's latch after the batch.  The
+    façade takes it from the forced batch instead of reading the latch
+    again, and its one-shot warning fires on the batch that latched."""
+    spec = SetSpec(capacity=64, backend=backend)        # 8 slots a shard
+    probe = ShardedDurableMap(spec, n_shards=8, n_device_groups=4)
+    m = ShardedDurableMap(spec, n_shards=8, n_device_groups=4)
+    keys = _keys_on_row(m.sspec, 5, 16)
+    warned_at, latched_at = [], None
+    for i in range(4):
+        batch = keys[4 * i:4 * i + 4]
+        ops = np.full(4, OP_INSERT, np.int32)
+        probe.state, fl = RT.apply_batch_v2_async(probe.state, ops, batch,
+                                                  batch, sspec=probe.sspec)
+        fl.force()
+        assert fl.overflow == bool(np.asarray(probe.state.overflow).any())
+        spans.names.clear()
+        with warnings.catch_warnings(record=True) as w:
+            warnings.simplefilter("always")
+            m.insert(batch)
+        assert spans.syncs() == ["registry.sync.batch"]
+        warned_at += [i for x in w if "overflow latched" in str(x.message)]
+        if latched_at is None and np.asarray(m.state.overflow).any():
+            latched_at = i
+        assert fl.overflow == (latched_at is not None)
+    assert latched_at == 2 and warned_at == [latched_at]
+    assert m.overflowed and m._overflow_warned
+
+
+@pytest.mark.parametrize("backend", ("bucket", "probe"))
+def test_pipelined_overflow_latch_needs_no_read(backend, spans):
+    """The pipelined path takes the latch from each batch it forces: the
+    warning fires once, when the batch that latched is forced, and
+    neither the forces nor ``pipeline_flush`` read the latch again."""
+    m = ShardedDurableMap(SetSpec(capacity=64, backend=backend),
+                          n_shards=8, n_device_groups=4, pipeline_depth=2)
+    keys = _keys_on_row(m.sspec, 5, 16)
+    spans.names.clear()
+    with warnings.catch_warnings(record=True) as w:
+        warnings.simplefilter("always")
+        for i in range(4):
+            m.insert(keys[4 * i:4 * i + 4])
+        m.pipeline_flush()
+    assert sum("overflow latched" in str(x.message) for x in w) == 1
+    assert spans.syncs() == ["registry.sync.batch"] * 4
+    assert m._overflow_warned and m.overflowed
+
+
+def _check_one_group_latch(use_shard_map: bool):
+    """Only shard row 5 (group 2 of 4) latches; every later batch's
+    packed output carries group 2's latch alone, even a batch whose lanes
+    all go to group 0, and the host ORs it into ``InFlight.overflow``."""
+    m = ShardedDurableMap(SetSpec(capacity=64, backend="probe"),
+                          n_shards=8, use_shard_map=use_shard_map,
+                          n_device_groups=0 if use_shard_map else 4)
+    assert RT.resolve_groups(m.sspec) == 4
+    assert RT._use_mesh(m.sspec, 4) == use_shard_map
+    with pytest.warns(RuntimeWarning, match="overflow latched"):
+        m.insert(_keys_on_row(m.sspec, 5, 12))           # 8 slots a shard
+    np.testing.assert_array_equal(np.asarray(m.state.overflow),
+                                  [0, 0, 0, 0, 0, 1, 0, 0])
+    keys = _keys_on_row(m.sspec, 0, 4)
+    ops = np.full(4, OP_CONTAINS, np.int32)
+    plan = RT.host_route(m.sspec, ops, keys, keys)
+    m.state, packed = RT._apply_v2(
+        m.state, jnp.asarray(plan.d_ops), jnp.asarray(plan.d_keys),
+        jnp.asarray(plan.d_vals), sspec=m.sspec, groups=plan.groups,
+        lane_budget=plan.lane_budget)
+    packed = np.asarray(packed)
+    assert packed.shape == (4, plan.d_ops.shape[1] + 2)
+    np.testing.assert_array_equal(packed[:, -1], [0, 0, 1, 0])
+    np.testing.assert_array_equal(packed[:, -2], 0)           # no drops
+    RT.release_plan(plan)
+    m.state, fl = RT.apply_batch_v2_async(m.state, ops, keys, keys,
+                                          sspec=m.sspec)
+    fl.force()
+    assert fl.overflow is True
+
+
+ONE_GROUP_LATCH_SCRIPT = textwrap.dedent("""
+    import os, sys
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax
+    assert jax.device_count() == 4
+    sys.path.insert(0, sys.argv[1])
+    from test_router_v2 import _check_one_group_latch
+    _check_one_group_latch(use_shard_map=True)
+    print("ONE_GROUP_LATCH OK")
+""")
+
+
+@pytest.mark.parametrize("path", ("vmap", "shard_map"))
+def test_overflow_latch_confined_to_one_group(path):
+    """The per-group overflow latch, ORed on the host: one group's shard
+    latches, on the vmap path and on a real 4-device ``shard_map`` mesh
+    (4 fake CPU devices, in a fresh process)."""
+    if path == "vmap":
+        _check_one_group_latch(use_shard_map=False)
+        return
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    env.pop("XLA_FLAGS", None)
+    r = subprocess.run([sys.executable, "-c", ONE_GROUP_LATCH_SCRIPT,
+                        os.path.join(REPO, "tests")], env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
+    assert "ONE_GROUP_LATCH OK" in r.stdout

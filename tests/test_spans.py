@@ -6,8 +6,8 @@
   2. THE SERVED PATH -- with ``TraceAnnotation`` replaced by a recorder,
      one sharded registry batch, one spine round and one crash show the
      layer spans by name and nesting, and exactly the ``*.sync.*`` spans
-     (one per device-to-host read) that the path makes: four per batch,
-     seven per spine round; the queue façade's, router v1's and the
+     (one per device-to-host read) that the path makes: one per batch,
+     four per spine round; the queue façade's, router v1's and the
      elastic map's reads are sync spans too.
 """
 import jax
@@ -120,6 +120,8 @@ def _batch(rng, b=64):
 
 
 def test_registry_batch_spans_and_four_syncs(spans):
+    """The name counts the four reads a batch made before its outputs
+    were packed into one; the test pins that one read."""
     m = _registry()
     rng = np.random.default_rng(0)
     m.apply(*_batch(rng))                 # compile outside the count
@@ -128,13 +130,10 @@ def test_registry_batch_spans_and_four_syncs(spans):
     names = spans.names()
     assert names[:3] == ["registry.route", "registry.launch",
                          "registry.force"]
-    assert spans.syncs() == ["registry.sync.results",
-                             "registry.sync.dropped",
-                             "registry.sync.kept",
-                             "registry.sync.overflow"]
+    # results, kept mask, dropped count and overflow latch: one read
+    assert spans.syncs() == ["registry.sync.batch"]
     parent = dict(spans.events)
-    for read in ("results", "dropped", "kept"):
-        assert parent[f"registry.sync.{read}"] == "registry.force"
+    assert parent["registry.sync.batch"] == "registry.force"
     assert parent["registry.route"] is None
 
 
@@ -145,14 +144,12 @@ def test_registry_get_reads_values_and_present(spans):
     m.get(keys)                           # compile outside the count
     spans.clear()
     np.testing.assert_array_equal(m.get(keys), keys + 1)
-    assert spans.syncs() == ["registry.sync.values",
-                             "registry.sync.present",
-                             "registry.sync.dropped",
-                             "registry.sync.kept",
-                             "registry.sync.overflow"]
+    assert spans.syncs() == ["registry.sync.batch"]
 
 
 def test_spine_round_spans_and_seven_syncs(spans):
+    """The name counts the seven reads a round made before the registry
+    packed its four into one; the test pins the four that remain."""
     m = MetricsRegistry()
     registry = _registry()
     qspec = QueueSpec(capacity=256)
@@ -170,11 +167,7 @@ def test_spine_round_spans_and_seven_syncs(spans):
         assert names.count(f"spine.{step}") == 1
     assert names.count("queue.enqueue") == 2
     assert names.count("queue.dequeue") == 2
-    assert spans.syncs() == ["registry.sync.results",
-                             "registry.sync.dropped",
-                             "registry.sync.kept",
-                             "registry.sync.overflow"] + \
-        ["queue.sync.ok"] * 3
+    assert spans.syncs() == ["registry.sync.batch"] + ["queue.sync.ok"] * 3
     parent = dict(spans.events)
     assert parent["registry.route"] == "spine.dispatch"
     assert parent["queue.sync.ok"] == "spine.force"
