@@ -330,9 +330,12 @@ def np_v1_drop_mask(keys: np.ndarray, *, n_shards: int, lane_budget: int
 # ---------------------------------------------------------------------------
 
 
+@functools.partial(jax.jit, static_argnames=("sspec",))
 def make_state(sspec: ShardSpec) -> SetState:
     """Stacked fresh state: every SetState leaf gains a leading shard axis
-    (dim0 == S).  Each slice is exactly ``engine.make_state(shard_spec)``."""
+    (dim0 == S).  Each slice is exactly ``engine.make_state(shard_spec)``.
+    One compiled program: built op by op it is ~50 host dispatches, which
+    a snapshot restart would pay on every crash."""
     base = E.make_state(sspec.shard_spec())
     return jax.tree.map(
         lambda x: jnp.repeat(x[None], sspec.n_shards, axis=0), base)
@@ -501,6 +504,12 @@ def recover(persisted: jax.Array, keys: jax.Array, values: jax.Array,
         return _dispatch(jax.vmap(
             lambda p, k, v: fn(p, k, v)), sspec)(persisted, keys, values)
     return _dispatch(jax.vmap(fn), sspec)(persisted, keys, values, stamp)
+
+
+def _delta_width(max_slots: int) -> int:
+    """The padded delta width of a hybrid recovery whose fullest shard has
+    ``max_slots`` delta slots: the next power of two, at least 8."""
+    return max(8, 1 << max(0, int(max_slots) - 1).bit_length())
 
 
 @functools.partial(jax.jit, static_argnames=("sspec",), donate_argnums=(0,))
@@ -980,43 +989,82 @@ class ShardedDurableMap(MetricsMixin):
         """Crash all shards and recover from the stored snapshot + each
         shard's stamp delta in ONE vmapped dispatch; bit-identical to
         ``crash_and_recover`` under the same adversary.  Staged-batch
-        abandonment follows the same rules.  Recovery psyncs: exactly 0."""
-        self._pre_crash()
-        if u is None:
-            u = np.random.default_rng(seed).random(
-                self.state.cur.shape).astype(np.float32)
-        n = self.spec.capacity
-        w = np.asarray(meta["watermark"], np.int32).reshape(-1, 1)
-        t0 = time.perf_counter()
-        crashed = crash(self.state, jnp.asarray(u))
-        mask = np.asarray(crashed[3]) > w                     # (S, N)
-        dmax = int(mask.sum(axis=1).max())
-        d = max(8, 1 << max(0, dmax - 1).bit_length())
-        delta_idx = np.full((self.n_shards, d), n, np.int32)
-        hist = np.asarray(meta["hist"], np.int64)             # (S, 5)
-        raw = planes["raw_stage"]
-        crash_stage = np.asarray(crashed[0])
-        n_delta = 0
-        for s in range(self.n_shards):
-            idx = np.flatnonzero(mask[s]).astype(np.int32)
-            delta_idx[s, :idx.size] = idx
-            n_delta += idx.size
-            hist[s] -= np.bincount(np.clip(raw[s, idx], 0, 4), minlength=5)
-            hist[s] += np.bincount(np.clip(crash_stage[s, idx], 0, 4),
-                                   minlength=5)
-        snap = self._snapshot_state(planes)
-        self.state = hybrid_recover(snap, *crashed,
-                                    jnp.asarray(delta_idx), sspec=self.sspec)
-        self.last_recovery_hist_shards = hist.astype(np.int32)
-        self.last_recovery_hist = self.last_recovery_hist_shards.sum(axis=0)
-        jax.block_until_ready(self.state.keys)
-        self.last_recovery_seconds = time.perf_counter() - t0
-        total = self.n_shards * n
-        self._metrics_post_recovery(scanned_slots=n_delta,
-                                    from_snapshot=total - n_delta,
-                                    from_delta=n_delta)
-        self._post_recovery_overflow()
+        abandonment follows the same rules.  Recovery psyncs: exactly 0.
+        Spans as ``crash_and_recover``'s, with ``registry.sync.delta``
+        (one read of the crash-time stamp and stage planes),
+        ``registry.delta`` (the host's delta index and histogram) and
+        ``registry.snapshot.load`` (the snapshot planes put back on the
+        device) before the rebuild."""
+        with span("registry.recover"):
+            self._pre_crash()
+            if u is None:
+                u = np.random.default_rng(seed).random(
+                    self.state.cur.shape).astype(np.float32)
+            n = self.spec.capacity
+            w = np.asarray(meta["watermark"], np.int32).reshape(-1, 1)
+            t0 = time.perf_counter()
+            with span("registry.crash"):
+                crashed = crash(self.state, jnp.asarray(u))
+            with span("registry.sync.delta"):
+                stamp, crash_stage = jax.device_get((crashed[3],
+                                                     crashed[0]))
+            with span("registry.delta"):
+                mask = stamp > w                                  # (S, N)
+                delta_idx = np.full((self.n_shards,
+                                     _delta_width(mask.sum(axis=1).max())),
+                                    n, np.int32)
+                hist = np.asarray(meta["hist"], np.int64)         # (S, 5)
+                raw = planes["raw_stage"]
+                n_delta = 0
+                for s in range(self.n_shards):
+                    idx = np.flatnonzero(mask[s]).astype(np.int32)
+                    delta_idx[s, :idx.size] = idx
+                    n_delta += idx.size
+                    hist[s] -= np.bincount(np.clip(raw[s, idx], 0, 4),
+                                           minlength=5)
+                    hist[s] += np.bincount(np.clip(crash_stage[s, idx], 0,
+                                                   4), minlength=5)
+            with span("registry.snapshot.load"):
+                snap = self._snapshot_state(planes)
+            with span("registry.rebuild"):
+                self.state = hybrid_recover(snap, *crashed,
+                                            jnp.asarray(delta_idx),
+                                            sspec=self.sspec)
+            self.last_recovery_hist_shards = hist.astype(np.int32)
+            self.last_recovery_hist = self.last_recovery_hist_shards.sum(
+                axis=0)
+            with span("registry.sync.recover_ready"):
+                jax.block_until_ready(self.state.keys)
+            self.last_recovery_seconds = time.perf_counter() - t0
+            total = self.n_shards * n
+            self._metrics_post_recovery(scanned_slots=n_delta,
+                                        from_snapshot=total - n_delta,
+                                        from_delta=n_delta)
+            self._post_recovery_overflow()
         return self
+
+    def precompile_hybrid(self, min_delta: int, max_delta: int) -> list:
+        """Compile the hybrid recovery for every delta width a restart
+        whose fullest shard has ``min_delta`` to ``max_delta`` delta slots
+        can realise (the powers of two between their widths), so that no
+        such restart compiles.  Runs each on an all-padding delta over a
+        copy of the live planes; the map's state is left as it was.
+        Returns the widths."""
+        planes = {f: np.asarray(getattr(self.state, f))
+                  for f in self._SNAP_FIELDS}
+        u = jnp.zeros(self.state.cur.shape, jnp.float32)
+        crashed = crash(self.state, u)
+        widths = []
+        d = _delta_width(min_delta)
+        while d <= _delta_width(max_delta):
+            out = hybrid_recover(self._snapshot_state(planes), *crashed,
+                                 jnp.full((self.n_shards, d),
+                                          self.spec.capacity, jnp.int32),
+                                 sspec=self.sspec)
+            jax.block_until_ready(out.keys)
+            widths.append(d)
+            d *= 2
+        return widths
 
     @property
     def psyncs(self):

@@ -39,6 +39,7 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.metrics import span
 from repro.store.checkpoint import CheckpointManager
 
 
@@ -81,8 +82,14 @@ class Snapshotter:
     step captures).  Metrics (optional; default: the structure's attached
     registry): ``span.<name>.snapshot`` duration histogram,
     ``<name>.snapshot_bytes_written`` counter,
-    ``<name>.snapshot_age_seconds`` gauge, and a ``<name>.snapshotter``
-    collector -- all reachable from ``MetricsRegistry.snapshot()``.
+    ``<name>.snapshot_age_seconds`` gauge, the ``<name>.recoveries_hybrid``
+    and ``<name>.recover_fallbacks`` counters (restarts through a
+    snapshot, restarts that fell back to the full scan), and a
+    ``<name>.snapshotter`` collector -- all reachable from
+    ``MetricsRegistry.snapshot()``.  Spans on the profiler's clock:
+    ``<name>.snapshot.capture``, ``.build`` and ``.save`` (the last two
+    on the snapshotter's thread), and ``<name>.snapshot.restore`` around
+    the store reads and the epoch fix of a restart.
     """
 
     def __init__(self, structure, directory: str,
@@ -144,15 +151,18 @@ class Snapshotter:
         self._last_step = step
         self._last_time = time.monotonic()
         t0 = time.perf_counter()
-        cap = self.structure.snapshot_capture()
+        with span(f"{self._name}.snapshot.capture"):
+            cap = self.structure.snapshot_capture()
         self._pending = self._pool.submit(self._build_and_save, step, cap,
                                           t0)
         return self._pending
 
     def _build_and_save(self, step: int, cap: dict, t0: float) -> int:
-        planes, meta = self.structure.snapshot_build(cap)
+        with span(f"{self._name}.snapshot.build"):
+            planes, meta = self.structure.snapshot_build(cap)
         b0 = self.store.bytes_written
-        self.store.save(step, planes, extra=meta)
+        with span(f"{self._name}.snapshot.save"):
+            self.store.save(step, planes, extra=meta)
         self.last_duration = time.perf_counter() - t0
         self._last_commit_time = time.monotonic()
         self.snapshots += 1
@@ -194,14 +204,23 @@ class Snapshotter:
                     #       step, so recovery proceeds from the last one
             self._pending = None
         step = self.store.latest_step()
+        restore = f"{self._name}.snapshot.restore"
         if step is None or not self.supports_hybrid:
+            self._count("recover_fallbacks")
             self.structure.crash_and_recover(u)
         else:
-            planes = self.store.restore(step)
-            meta = self.store.extra(step)
+            with span(restore):
+                planes = self.store.restore(step)
+                meta = self.store.extra(step)
             self.structure.hybrid_crash_and_recover(planes, meta, u)
-        self._fix_epoch()
+            self._count("recoveries_hybrid")
+        with span(restore):
+            self._fix_epoch()
         return self.structure
+
+    def _count(self, what: str) -> None:
+        if self._m is not None:
+            self._m.counter(f"{self._name}.{what}").inc()
 
     def _fix_epoch(self):
         """Stamp-generation monotonicity across snapshots WITHOUT

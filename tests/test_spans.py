@@ -9,6 +9,10 @@
      (one per device-to-host read) that the path makes: one per batch,
      four per spine round; the queue façade's, router v1's and the
      elastic map's reads are sync spans too.
+  3. THE SNAPSHOT RESTART -- a snapshot shows its capture, build and
+     save; a restart through it shows its restore and the hybrid
+     recovery's steps, with exactly three sync spans and no psync; a
+     restart with no committed snapshot counts a fallback.
 """
 import jax
 import numpy as np
@@ -230,3 +234,71 @@ def test_elastic_map_overflow_latch_is_a_sync(spans):
     spans.clear()
     assert not m.overflowed
     assert spans.syncs() == ["registry.sync.overflow"]
+
+
+# ---------------------------------------------------------------------------
+# 3. The snapshot restart (DESIGN.md §11)
+# ---------------------------------------------------------------------------
+
+
+def _snapshotted(tmp_path):
+    from repro.store.snapshot import Snapshotter
+    m = ShardedDurableMap(SetSpec(capacity=1024, backend="bucket"),
+                          n_shards=4, metrics=MetricsRegistry(),
+                          metrics_name="registry")
+    return m, Snapshotter(m, str(tmp_path / "snap"))
+
+
+def test_snapshot_spans(spans, tmp_path):
+    m, sn = _snapshotted(tmp_path)
+    m.insert(np.arange(1, 100, dtype=np.int32))
+    spans.clear()
+    sn.snapshot()
+    sn.wait()
+    assert spans.events == [("registry.snapshot.capture", None),
+                            ("registry.snapshot.build", None),
+                            ("registry.snapshot.save", None)]
+    sn.close()
+
+
+def test_hybrid_restart_spans_and_three_syncs(spans, tmp_path):
+    m, sn = _snapshotted(tmp_path)
+    keys = np.arange(1, 200, dtype=np.int32)
+    m.insert(keys[:150])
+    sn.snapshot()
+    sn.wait()
+    m.insert(keys[150:])
+    spans.clear()
+    sn.recover(np.random.default_rng(4).random(
+        m.state.cur.shape).astype(np.float32))
+    top = [n for n, p in spans.events if p is None]
+    assert top == ["registry.snapshot.restore", "registry.recover",
+                   "registry.snapshot.restore"]
+    inner = [n for n, p in spans.events if p == "registry.recover"]
+    assert inner == ["registry.crash", "registry.sync.delta",
+                     "registry.delta", "registry.snapshot.load",
+                     "registry.rebuild", "registry.sync.recover_ready",
+                     "registry.sync.overflow"]
+    assert spans.syncs() == ["registry.sync.delta",
+                             "registry.sync.recover_ready",
+                             "registry.sync.overflow"]
+    c = m._m.snapshot()["counters"]
+    assert c["registry.recoveries_hybrid"] == 1
+    assert c["registry.recovery_psyncs"] == 0
+    assert c.get("registry.recover_fallbacks", 0) == 0
+    assert np.asarray(m.contains(keys)).all()     # completed inserts
+    sn.close()
+
+
+def test_restart_without_a_snapshot_counts_a_fallback(spans, tmp_path):
+    m, sn = _snapshotted(tmp_path)
+    m.insert(np.arange(1, 50, dtype=np.int32))
+    spans.clear()
+    sn.recover()
+    top = [n for n, p in spans.events if p is None]
+    assert top == ["registry.recover", "registry.snapshot.restore"]
+    c = m._m.snapshot()["counters"]
+    assert c["registry.recover_fallbacks"] == 1
+    assert c.get("registry.recoveries_hybrid", 0) == 0
+    assert c["registry.recovery_psyncs"] == 0
+    sn.close()
