@@ -525,88 +525,108 @@ def supports_hybrid_recovery(spec: SetSpec) -> bool:
     return not get_backend(spec.backend).builds_probe_table
 
 
-def _delta_bucket_patch(snap: SetState, keys2, cur2, delta_idx, gi, valid,
-                        member_d, *, spec: SetSpec):
-    """Re-canonicalize exactly the bucket rows affected by the delta.
+def hybrid_patch_path(spec: SetSpec, d: int,
+                      snap_overflowed: bool = False) -> Optional[str]:
+    """The bucket-index patch a hybrid restart of padded delta width ``d``
+    takes: ``"delta"`` (O(delta): candidates from the snapshot's affected
+    rows, its stash and the delta slots) while the candidate bound
+    ``K = 2*d*w + s + d`` is below the capacity, else ``"full"`` (the
+    whole-pool ``bucket_init`` on the merged planes, which at that width is
+    the cheaper pass).  A snapshot whose stash overflowed keeps only the
+    first ``s`` spills, so its index no longer names every member and it
+    takes ``"full"`` too.  None for backends without a bucket index.
+    Static: both inputs are known on the host before the call."""
+    nb, w, s = get_backend(spec.backend).state_geometry(spec)
+    if nb == 0:
+        return None
+    if snap_overflowed or 2 * d * w + s + d >= spec.capacity:
+        return "full"
+    return "delta"
 
-    Candidates = every live node hashing to an affected bucket (the buckets
-    of the delta slots' snapshot-time AND crash-time keys).  They are
-    gathered in ascending node-id order, so rank-within-bucket among the
-    candidates equals rank-within-bucket in the full ``build_buckets``
-    repack -- cleared rows rebuilt this way are bit-identical to a full
-    rebuild.  The dense stash is globally id-ordered, so it is recomputed
-    from (kept unaffected spills) + (affected-bucket spills) with the same
-    ``jnp.where(size=s)`` pack ``bucket_init`` uses."""
+
+def _delta_bucket_patch(snap: SetState, keys2, cur2, gi, valid, member_d,
+                        delta_idx, *, spec: SetSpec):
+    """Re-canonicalize exactly the bucket rows affected by the delta, in
+    O(delta + stash) work.
+
+    An affected bucket is one a delta slot's snapshot-time OR crash-time
+    key hashes to.  Its live members after the crash are in the snapshot's
+    row for it, in the snapshot's stash, or among the delta slots (the
+    snapshot is canonical and did not overflow, see
+    :func:`hybrid_patch_path`); an unaffected bucket's row and spills are
+    unchanged.  One sort of those candidates by (bucket, node id) --
+    behind one marker per affected bucket, id -1 -- drops the ids a row
+    and the delta both name and gives each member its rank within its
+    bucket in ascending-id order, exactly as ``build_buckets`` ranks it.
+    Members of a group without a marker are the stash's unaffected
+    spills.  The stash is then the ``s`` smallest spill ids, by a second
+    sort of the same width, as ``bucket_init`` packs it.  Every gather,
+    scatter and sort here is O(delta + stash) wide; the one pass over the
+    whole table is the elementwise clear of the affected rows."""
     from repro.core.nvm import hash32, EMPTY
     n = spec.capacity
     nb, w = spec.bucket_geometry()
     s = spec.stash_size
-    d = delta_idx.shape[0]
 
-    # affected buckets: where the delta slots' old and new keys hash
-    old_member = valid & (snap.cur[gi] == VALID)
-    new_member = valid & member_d
-    b_old = (hash32(snap.keys[gi]) % jnp.uint32(nb)).astype(jnp.int32)
-    b_new = (hash32(keys2[gi]) % jnp.uint32(nb)).astype(jnp.int32)
-    aff = jnp.zeros((nb + 1,), jnp.bool_) \
-        .at[jnp.where(old_member, b_old, nb)].set(True) \
-        .at[jnp.where(new_member, b_new, nb)].set(True)[:nb]
+    def bucket(k):
+        return (hash32(k) % jnp.uint32(nb)).astype(jnp.int32)
 
-    # candidates: all live members of affected buckets, ascending node id.
-    # K bounds them: <= w per affected bucket row (<= 2 buckets per delta
-    # slot) + every pre-existing stash spill + the delta slots themselves;
-    # past K the stash has overflowed (> s spills) and the latch fires.
-    live2 = cur2 == VALID
-    h2 = (hash32(keys2) % jnp.uint32(nb)).astype(jnp.int32)
-    cand_mask = live2 & aff[h2]
-    k = min(n, 2 * d * w + s + d)
-    cand = jnp.where(cand_mask, size=k, fill_value=n)[0].astype(jnp.int32)
-    cvalid = cand < n
-    cg = jnp.where(cvalid, cand, 0)
-    ck = jnp.where(cvalid, keys2[cg], 0)
-    cb = jnp.where(cvalid, h2[cg], nb)
+    # affected buckets, nb where the slot was / is no member (2D, dups kept)
+    ab = jnp.concatenate([
+        jnp.where(valid & (snap.cur[gi] == VALID), bucket(snap.keys[gi]), nb),
+        jnp.where(valid & member_d, bucket(keys2[gi]), nb)])
+    # candidates: the affected rows (read way by way: a gather of whole
+    # rows makes XLA relayout the table), the stash, the delta slots
+    ways = jnp.arange(w, dtype=jnp.int32)
+    rb = jnp.repeat(ab, w)
+    rows = jnp.where(rb < nb, snap.bids[jnp.minimum(rb, nb - 1),
+                                        jnp.tile(ways, ab.shape[0])], EMPTY)
+    cid = jnp.concatenate([rows, snap.sids, delta_idx])
+    cid = jnp.where((cid >= 0) & (cid < n), cid, n)
+    cg = jnp.minimum(cid, n - 1)
+    ck = keys2[cg]
+    live = (cid < n) & (cur2[cg] == VALID)
+    cb = jnp.concatenate([ab, jnp.where(live, bucket(ck), nb)])
+    cid = jnp.concatenate([jnp.full(ab.shape, -1, jnp.int32),
+                           jnp.where(live, cid, n)])
+    ck = jnp.concatenate([jnp.zeros(ab.shape, ck.dtype), ck])
+    sb, sid, sk = lax.sort((cb, cid, ck), num_keys=2, is_stable=False)
 
-    # rank within bucket among candidates (== rank in the full repack:
-    # stable argsort groups buckets preserving ascending-id order)
-    order = jnp.argsort(cb)
-    sb = cb[order]
-    pos = jnp.arange(k, dtype=jnp.int32)
-    group_start = jnp.full((nb + 1,), k, jnp.int32).at[sb].min(
-        pos, mode="drop")
-    rank = pos - group_start[jnp.clip(sb, 0, nb)]
-    ok = (sb < nb) & (rank < w)
+    pos = jnp.arange(sb.shape[0], dtype=jnp.int32)
+    first = jnp.concatenate([jnp.ones((1,), jnp.bool_), sb[1:] != sb[:-1]])
+    dup = jnp.concatenate([jnp.zeros((1,), jnp.bool_), sid[1:] == sid[:-1]])
+    member = (sb < nb) & (sid >= 0) & ~dup
+    # a group is affected iff its first entry is a marker; rank = members
+    # before this one in its group (cummax carries the group's start)
+    aff = (lax.cummax(jnp.where(first, 2 * pos + (sid < 0), 0)) & 1) > 0
+    m = member.astype(jnp.int32)
+    before = jnp.cumsum(m) - m
+    rank = before - lax.cummax(jnp.where(first, before, 0))
+    in_row = member & aff & (rank < w)
+    spilled = member & ~in_row
 
-    # clear affected rows, rebuild them canonically
-    bkeys = jnp.where(aff[:, None], 0, snap.bkeys)
-    bids = jnp.where(aff[:, None], EMPTY, snap.bids)
-    tb = jnp.where(ok, sb, nb)
-    tw = jnp.where(ok, rank, 0)
-    bkeys = bkeys.at[tb, tw].set(ck[order], mode="drop")
-    bids = bids.at[tb, tw].set(cand[order], mode="drop")
+    tb = jnp.where(in_row, sb, nb)                   # OOB scatter => dropped
+    tw = jnp.where(in_row, rank, 0)
+    # an add, not a set: ab repeats buckets, and a set to repeated
+    # indices compiles to one more sort
+    cleared = (jnp.zeros((nb + 1,), jnp.int32).at[ab].add(1) > 0)[:nb, None]
+    bkeys = jnp.where(cleared, 0, snap.bkeys).at[tb, tw].set(sk, mode="drop")
+    bids = jnp.where(cleared, EMPTY, snap.bids) \
+        .at[tb, tw].set(sid, mode="drop")
 
-    # stash: spills = kept unaffected spills + affected-bucket overflow,
-    # re-packed in ascending node-id order exactly like bucket_init
-    prior = snap.sids >= 0
-    pb = (hash32(snap.skeys) % jnp.uint32(nb)).astype(jnp.int32)
-    keep = prior & ~aff[jnp.clip(pb, 0, nb - 1)]
-    kept_ids = jnp.where(keep, snap.sids, 0)
-    spilled = (~ok) & (sb < nb)
-    spill_ids = jnp.where(spilled, cand[order], 0)
-    spill_mask = jnp.zeros((n,), jnp.int32) \
-        .at[kept_ids].max(keep.astype(jnp.int32)) \
-        .at[spill_ids].max(spilled.astype(jnp.int32)) > 0
-    spill = jnp.sum(spill_mask.astype(jnp.int32))
-    idx = jnp.where(spill_mask, size=s, fill_value=-1)[0].astype(jnp.int32)
-    got = idx >= 0
-    sids = jnp.where(got, idx, EMPTY)
-    skeys = jnp.where(got, keys2[jnp.clip(idx, 0)], 0)
+    spill = jnp.sum(spilled.astype(jnp.int32))
+    first_s = lax.sort(jnp.where(spilled, sid, n), is_stable=False)[:s]
+    got = first_s < n
+    sids = jnp.where(got, first_s, EMPTY)
+    skeys = jnp.where(got, keys2[jnp.minimum(first_s, n - 1)], 0)
     return bkeys, bids, skeys, sids, jnp.minimum(spill, s), spill > s
 
 
 def hybrid_recover_impl(snap: SetState, persisted: jax.Array,
                         keys: jax.Array, values: jax.Array,
                         stamp: jax.Array, delta_idx: jax.Array,
-                        *, spec: SetSpec) -> SetState:
+                        *, spec: SetSpec,
+                        snap_overflowed: bool = False) -> SetState:
     """Unjitted hybrid-recovery body (vmappable over a stacked shard axis).
 
     ``snap`` is the canonical snapshot state at watermark W;
@@ -615,7 +635,9 @@ def hybrid_recover_impl(snap: SetState, persisted: jax.Array,
     with ``capacity``).  Slots outside the delta are bit-identical between
     capture and crash (every durable mutation stamps its slot inside the
     commit scatter), so classification -- the ``recovery_scan`` -- runs
-    over the gathered delta only.  No psync is ever issued."""
+    over the gathered delta only.  ``snap_overflowed`` (static: the stored
+    latch, read on the host) and D choose the index patch
+    (:func:`hybrid_patch_path`).  No psync is ever issued."""
     backend = get_backend(spec.backend)
     if backend.builds_probe_table:
         raise ValueError(
@@ -647,26 +669,33 @@ def hybrid_recover_impl(snap: SetState, persisted: jax.Array,
         size=size2,
         epoch=jnp.maximum(jnp.max(stamp2), 0) + 1,
     )
-    nb, _, _ = backend.state_geometry(spec)
-    if nb > 0:       # bucket backend: canonical O(delta) index patch
-        bkeys, bids, skeys, sids, stash_n, ovf = _delta_bucket_patch(
-            snap, keys2, cur2, delta_idx, gi, valid, member_d, spec=spec)
-        state = state._replace(bkeys=bkeys, bids=bids, skeys=skeys,
-                               sids=sids, stash_n=stash_n, overflow=ovf)
-    else:            # scan backend: no volatile index to patch
-        state = state._replace(overflow=jnp.zeros((), jnp.bool_))
-    return state
+    path = hybrid_patch_path(spec, delta_idx.shape[0], snap_overflowed)
+    if path is None:     # scan backend: no volatile index to patch
+        return state._replace(overflow=jnp.zeros((), jnp.bool_))
+    if path == "delta":
+        index = _delta_bucket_patch(snap, keys2, cur2, gi, valid, member_d,
+                                    scat, spec=spec)
+    else:
+        nb, w = spec.bucket_geometry()
+        index = hp_ops.bucket_init(keys2, cur2, nb=nb, w=w,
+                                   s=spec.stash_size)
+    bkeys, bids, skeys, sids, stash_n, ovf = index
+    return state._replace(bkeys=bkeys, bids=bids, skeys=skeys, sids=sids,
+                          stash_n=stash_n, overflow=ovf)
 
 
-@functools.partial(jax.jit, static_argnames=("spec",), donate_argnums=(0,))
+@functools.partial(jax.jit, static_argnames=("spec", "snap_overflowed"),
+                   donate_argnums=(0,))
 def hybrid_recover(snap: SetState, persisted: jax.Array, keys: jax.Array,
                    values: jax.Array, stamp: jax.Array,
-                   delta_idx: jax.Array, *, spec: SetSpec) -> SetState:
+                   delta_idx: jax.Array, *, spec: SetSpec,
+                   snap_overflowed: bool = False) -> SetState:
     """Jitted snapshot + delta-log recovery: O(delta) work on top of the
     restored snapshot, bit-identical to ``recover`` on the same crash
     planes (pinned by tests/test_snapshot.py)."""
     return hybrid_recover_impl(snap, persisted, keys, values, stamp,
-                               delta_idx, spec=spec)
+                               delta_idx, spec=spec,
+                               snap_overflowed=snap_overflowed)
 
 
 def export_pool(state: SetState) -> dict:
@@ -779,7 +808,8 @@ class MetricsMixin:
 
     def _metrics_post_recovery(self, scanned_slots: int,
                                from_snapshot: int = 0,
-                               from_delta: Optional[int] = None):
+                               from_delta: Optional[int] = None,
+                               patch: Optional[str] = None):
         """Record the recovery: duration, scanned-slot gauges, and the
         recovery-psync counter (exactly 0 by construction -- payloads are
         already durable; the counter existing makes that checkable).
@@ -789,7 +819,10 @@ class MetricsMixin:
         recovered state to its sources: ``from_snapshot`` slots restored
         from the latest snapshot vs ``from_delta`` slots re-scanned because
         their stamp was newer than the watermark.  A full-pool recovery is
-        all-delta (from_snapshot=0, from_delta=scanned_slots)."""
+        all-delta (from_snapshot=0, from_delta=scanned_slots).  ``patch``
+        names the bucket-index patch a hybrid restart took
+        (:func:`hybrid_patch_path`), counted as
+        ``<name>.hybrid_patch_delta`` or ``<name>.hybrid_patch_full``."""
         if self._m is None:
             return
         if from_delta is None:
@@ -797,6 +830,8 @@ class MetricsMixin:
         m, name = self._m, self._m_name
         m.counter(f"{name}.recoveries").inc()
         m.counter(f"{name}.recovery_psyncs").inc(self.psyncs)
+        if patch is not None:
+            m.counter(f"{name}.hybrid_patch_{patch}").inc()
         m.gauge(f"{name}.last_recovery_scanned_slots").set(scanned_slots)
         m.gauge(f"{name}.last_recovery_from_snapshot_slots").set(
             from_snapshot)
@@ -993,9 +1028,11 @@ class DurableMap(MetricsMixin):
         stamp_h = np.asarray(crashed[3])
         delta = np.flatnonzero(stamp_h > w).astype(np.int32)
         delta_idx = pad_delta(delta, n)
+        ovf = bool(np.any(planes["overflow"]))
         snap = self._snapshot_state(planes)
         self.state = hybrid_recover(snap, *crashed,
-                                    jnp.asarray(delta_idx), spec=self.spec)
+                                    jnp.asarray(delta_idx), spec=self.spec,
+                                    snap_overflowed=ovf)
         # Exact O(delta) stage-histogram correction: the canonical
         # snapshot collapsed DELETED slots to FREE, so the stored
         # capture-time raw stages reconstruct what a full scan over the
@@ -1009,9 +1046,10 @@ class DurableMap(MetricsMixin):
         self.last_recovery_hist = hist.astype(np.int32)
         jax.block_until_ready(self.state.keys)
         self.last_recovery_seconds = time.perf_counter() - t0
-        self._metrics_post_recovery(scanned_slots=int(delta.size),
-                                    from_snapshot=n - int(delta.size),
-                                    from_delta=int(delta.size))
+        self._metrics_post_recovery(
+            scanned_slots=int(delta.size), from_snapshot=n - int(delta.size),
+            from_delta=int(delta.size),
+            patch=hybrid_patch_path(self.spec, delta_idx.size, ovf))
         self._post_recovery_overflow()
         return self
 

@@ -512,15 +512,19 @@ def _delta_width(max_slots: int) -> int:
     return max(8, 1 << max(0, int(max_slots) - 1).bit_length())
 
 
-@functools.partial(jax.jit, static_argnames=("sspec",), donate_argnums=(0,))
+@functools.partial(jax.jit, static_argnames=("sspec", "snap_overflowed"),
+                   donate_argnums=(0,))
 def hybrid_recover(snap: SetState, persisted: jax.Array, keys: jax.Array,
                    values: jax.Array, stamp: jax.Array,
-                   delta_idx: jax.Array, *, sspec: ShardSpec) -> SetState:
+                   delta_idx: jax.Array, *, sspec: ShardSpec,
+                   snap_overflowed: bool = False) -> SetState:
     """Per-shard snapshot + delta-log recovery in ONE vmapped dispatch:
     every leading axis is the shard axis (``delta_idx`` is (S, D), padded
-    per shard with the shard capacity).  Bit-identical to :func:`recover`
-    on the same crash planes (DESIGN.md §11)."""
-    fn = functools.partial(E.hybrid_recover_impl, spec=sspec.shard_spec())
+    per shard with the shard capacity; ``snap_overflowed``: any shard's
+    stored latch).  Bit-identical to :func:`recover` on the same crash
+    planes (DESIGN.md §11)."""
+    fn = functools.partial(E.hybrid_recover_impl, spec=sspec.shard_spec(),
+                           snap_overflowed=snap_overflowed)
     return _dispatch(jax.vmap(fn), sspec)(snap, persisted, keys, values,
                                           stamp, delta_idx)
 
@@ -1026,10 +1030,12 @@ class ShardedDurableMap(MetricsMixin):
                                                    4), minlength=5)
             with span("registry.snapshot.load"):
                 snap = self._snapshot_state(planes)
+            ovf = bool(np.any(planes["overflow"]))
             with span("registry.rebuild"):
                 self.state = hybrid_recover(snap, *crashed,
                                             jnp.asarray(delta_idx),
-                                            sspec=self.sspec)
+                                            sspec=self.sspec,
+                                            snap_overflowed=ovf)
             self.last_recovery_hist_shards = hist.astype(np.int32)
             self.last_recovery_hist = self.last_recovery_hist_shards.sum(
                 axis=0)
@@ -1037,9 +1043,11 @@ class ShardedDurableMap(MetricsMixin):
                 jax.block_until_ready(self.state.keys)
             self.last_recovery_seconds = time.perf_counter() - t0
             total = self.n_shards * n
-            self._metrics_post_recovery(scanned_slots=n_delta,
-                                        from_snapshot=total - n_delta,
-                                        from_delta=n_delta)
+            self._metrics_post_recovery(
+                scanned_slots=n_delta, from_snapshot=total - n_delta,
+                from_delta=n_delta,
+                patch=E.hybrid_patch_path(self.spec, delta_idx.shape[1],
+                                          ovf))
             self._post_recovery_overflow()
         return self
 

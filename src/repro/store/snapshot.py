@@ -84,7 +84,9 @@ class Snapshotter:
     ``<name>.snapshot_bytes_written`` counter,
     ``<name>.snapshot_age_seconds`` gauge, the ``<name>.recoveries_hybrid``
     and ``<name>.recover_fallbacks`` counters (restarts through a
-    snapshot, restarts that fell back to the full scan), and a
+    snapshot, restarts that fell back to the full scan; the structure
+    adds ``<name>.hybrid_patch_delta`` and ``<name>.hybrid_patch_full``,
+    the bucket-index patch each hybrid restart took), and a
     ``<name>.snapshotter`` collector -- all reachable from
     ``MetricsRegistry.snapshot()``.  Spans on the profiler's clock:
     ``<name>.snapshot.capture``, ``.build`` and ``.save`` (the last two

@@ -8,7 +8,8 @@ Pins the PR-9 acceptance surface:
   * hybrid recovery (latest committed snapshot + the ``stamp > W`` delta)
     is BIT-IDENTICAL to the full-pool ``recovery_scan`` rebuild under the
     same crash adversary, across backends, modes, removes/slot-reuse,
-    zero and large deltas, the sharded runtime, and the durable queue;
+    zero and large deltas, the sharded runtime, and the durable queue,
+    on both sides of the bucket-index patch's O(delta) threshold;
   * the mutation path pays ZERO extra psyncs for snapshotting (the op
     stream doubles as the delta log) and recovery itself psyncs exactly 0;
   * OracleSet / OracleQueue conformance holds through a snapshot
@@ -25,6 +26,9 @@ import pytest
 
 from repro.core import (DurableMap, DurableQueue, OracleQueue, OracleSet,
                         QueueSpec, SetSpec, ShardedDurableMap)
+from repro.core import engine as E
+from repro.core.nvm import FREE, VALID, hash32
+from repro.kernels.hash_probe import ops as hp_ops
 from repro.obs.metrics import MetricsRegistry
 from repro.store.checkpoint import CheckpointManager
 from repro.store.snapshot import SnapshotPolicy, Snapshotter
@@ -211,6 +215,143 @@ def test_sharded_hybrid_bit_identical(tmp_path):
     sn.recover(u)
     _assert_states_equal(m.state, ref.state)
     sn.close()
+
+
+# ---------------------------------------------------------------------------
+# the bucket-index patch: O(delta) below the candidate bound, whole pool
+# above it (engine.hybrid_patch_path), bit-identical either way
+# ---------------------------------------------------------------------------
+
+# case: (SetSpec kwargs, shards, live keys at the snapshot, delta inserts,
+#        delta removes, expected patch).  Removes run first, so the inserts
+#        re-use the slots they free.
+PATCH_CASES = {
+    "map": (dict(capacity=4096), None, 400, 40, 20, "delta"),
+    "sharded": (dict(capacity=4 * 2048), 4, 600, 60, 30, "delta"),
+    # 64 buckets of 8 ways for 560 keys: spills kept and re-packed
+    "crowded": (dict(capacity=4096, n_buckets=64, stash_size=256), None,
+                560, 40, 20, "delta"),
+    # the delta's inserts spill past the 24-slot stash: the latch fires
+    "stash_overflow": (dict(capacity=4096, n_buckets=32, bucket_width=4,
+                            stash_size=24), None, 120, 48, 0, "delta"),
+    "zero_delta": (dict(capacity=4096), None, 400, 0, 0, "delta"),
+    # 200 delta slots pad to 256: K = 2*256*8 + 128 + 256 >= 1024
+    "whole_pool": (dict(capacity=1024), None, 300, 150, 60, "full"),
+    # the snapshot's own stash overflowed: its index misses members
+    "snapshot_overflowed": (dict(capacity=4096, n_buckets=32,
+                                 bucket_width=4, stash_size=16), None,
+                            200, 10, 5, "full"),
+}
+
+
+@pytest.mark.parametrize("case", list(PATCH_CASES))
+def test_hybrid_patch_bit_identical(tmp_path, case):
+    kw, shards, n_live, n_ins, n_rem, path = PATCH_CASES[case]
+    spec = SetSpec(backend="bucket", **kw)
+    if shards is None:
+        mk = lambda **m: DurableMap(spec, **m)
+    else:
+        mk = lambda **m: ShardedDurableMap(spec, n_shards=shards, **m)
+    m = mk(metrics=MetricsRegistry())
+    name = m._m_name
+    rng = np.random.default_rng(sorted(PATCH_CASES).index(case))
+    keys = (rng.permutation(1 << 20)[:n_live + n_ins] + 1).astype(np.int32)
+    m.insert(keys[:n_live], keys[:n_live] * 5)
+    sn = Snapshotter(m, str(tmp_path / "snap"))
+    sn.snapshot()
+    sn.wait()
+    snap_planes = {f: np.asarray(getattr(m.state, f))
+                   for f in ("keys", "cur", "sids", "overflow")}
+    if n_rem:
+        m.remove(keys[:n_rem])
+    if n_ins:
+        m.insert(keys[n_live:], keys[n_live:] * 5)
+    ref = mk()
+    ref.state = _copy_state(m.state)
+    u = _u(rng, m.state.cur.shape)
+    ref.crash_and_recover(u)
+    sn.recover(u)
+    _assert_states_equal(m.state, ref.state)
+    np.testing.assert_array_equal(m.last_recovery_hist,
+                                  ref.last_recovery_hist)
+    assert m.psyncs == 0
+    c = m._m.snapshot()["counters"]
+    other = {"delta": "full", "full": "delta"}[path]
+    assert c[f"{name}.hybrid_patch_{path}"] == 1
+    assert c.get(f"{name}.hybrid_patch_{other}", 0) == 0
+    # the case exercises what its name says
+    snap_ovf = bool(np.any(snap_planes["overflow"]))
+    assert snap_ovf == (case == "snapshot_overflowed")
+    if case == "stash_overflow":
+        assert m.overflowed and ref.overflowed
+    if case == "crowded":
+        before = set(snap_planes["sids"][snap_planes["sids"] >= 0].tolist())
+        after = set(np.asarray(m.state.sids)[
+            np.asarray(m.state.sids) >= 0].tolist())
+        assert before & after and before != after    # kept and re-packed
+    if case == "map":
+        # a removed slot re-used by a key of another bucket
+        nb, _ = spec.bucket_geometry()
+        old, new = snap_planes["keys"], np.asarray(m.state.keys)
+        live = (snap_planes["cur"] == VALID) \
+            & (np.asarray(m.state.cur) == VALID)
+        h = lambda k: np.asarray(hash32(jnp.asarray(k))) % nb
+        assert (live & (old != new) & (h(old) != h(new))).any()
+    sn.close()
+
+
+def _patch_inputs(seed, n, nb, w, s, d, fill):
+    """A canonical snapshot of a random member set, and a random delta of
+    up to ``d`` slots over it (removes, fresh inserts, re-keyed slots and
+    keys kept in place), as ``_delta_bucket_patch`` takes them."""
+    rng = np.random.default_rng(seed)
+    spec = SetSpec(capacity=n, backend="bucket", n_buckets=nb,
+                   bucket_width=w, stash_size=s)
+    cur = np.where(rng.random(n) < fill, VALID, FREE).astype(np.int32)
+    keys = np.where(cur == VALID, rng.integers(1, 1 << 30, n),
+                    0).astype(np.int32)
+    index = hp_ops.bucket_init(jnp.asarray(keys), jnp.asarray(cur),
+                               nb=nb, w=w, s=s)
+    snap = E.make_state(spec)._replace(
+        keys=jnp.asarray(keys), cur=jnp.asarray(cur),
+        **dict(zip(("bkeys", "bids", "skeys", "sids", "stash_n",
+                    "overflow"), index)))
+    used = int(rng.integers(d // 2, d + 1))
+    slots = np.sort(rng.choice(n, used, replace=False)).astype(np.int32)
+    delta = np.full((d,), n, np.int32)
+    delta[:used] = slots
+    member = np.zeros((d,), bool)
+    member[:used] = rng.random(used) < 0.6
+    same = rng.random(used) < 0.3             # key kept in place
+    new_keys = np.where(same & (keys[slots] != 0), keys[slots],
+                        rng.integers(1, 1 << 30, used)).astype(np.int32)
+    keys2, cur2 = keys.copy(), cur.copy()
+    keys2[slots] = np.where(member[:used], new_keys, 0)
+    cur2[slots] = np.where(member[:used], VALID, FREE)
+    valid = delta < n
+    return spec, snap, (jnp.asarray(keys2), jnp.asarray(cur2),
+                        jnp.asarray(np.where(valid, delta, 0)),
+                        jnp.asarray(valid), jnp.asarray(member),
+                        jnp.asarray(delta))
+
+
+# n=512, 64 buckets of 4 ways, a 24-slot stash: K = 9d + 24 < 512 up to
+# d = 32, so widths 8-32 take the delta patch and 64-128 the whole pool
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("d,path", [(8, "delta"), (16, "delta"),
+                                    (32, "delta"), (64, "full"),
+                                    (128, "full")])
+def test_delta_bucket_patch_matches_bucket_init(seed, d, path):
+    n, nb, w, s = 512, 64, 4, 24
+    spec, snap, args = _patch_inputs(seed, n, nb, w, s, d, fill=0.3)
+    assert E.hybrid_patch_path(spec, d) == path
+    assert not bool(snap.overflow)            # a sound snapshot
+    got = E._delta_bucket_patch(snap, *args, spec=spec)
+    want = hp_ops.bucket_init(args[0], args[1], nb=nb, w=w, s=s)
+    for f, a, b in zip(("bkeys", "bids", "skeys", "sids", "stash_n",
+                        "overflow"), got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=f"{f} diverged")
 
 
 # ---------------------------------------------------------------------------
