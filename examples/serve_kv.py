@@ -1,6 +1,7 @@
-"""Serving example: batched generation + the durable request registry
-(crash-safe completion tracking via a SOFT DurableMap on the bucket
-backend, i.e. the Pallas hash_probe lookup / recovery_scan recovery path).
+"""Serving example: the durable request/completion spine around a
+crash-safe completion registry (a SOFT DurableMap on the bucket backend,
+i.e. the Pallas hash_probe lookup / recovery_scan recovery path), with a
+crash drill that proves no acknowledged request is lost.
 
 Run:  PYTHONPATH=src python examples/serve_kv.py
 """
@@ -8,9 +9,7 @@ from repro.launch import serve as S
 
 
 def main():
-    S.main(["--arch", "qwen3-32b-smoke", "--requests", "8",
-            "--prompt-len", "32", "--gen", "16", "--crash",
-            "--backend", "bucket"])
+    S.main(["--requests", "8", "--crash", "--backend", "bucket", "--queue"])
 
 
 if __name__ == "__main__":

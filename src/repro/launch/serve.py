@@ -1,37 +1,36 @@
-"""Serving driver: batched prefill + decode with a durable request
-registry (the paper's set as serving metadata).
+"""Serving driver: a CLI drill of the durable request registry and the
+request/completion spine.
 
-Completed request ids are inserted into a SOFT DurableMap; a crash loses
-the volatile index but not the registry, so after recovery the server
-knows exactly which requests had completed (no double-billing /
-re-generation) -- durable linearizability doing real work.  --backend
-picks the registry's index backend ("bucket" = the Pallas hash_probe /
-recovery_scan kernel path, DESIGN.md §4); --shards N > 1 swaps in the
-hash-partitioned ShardedDurableMap (one vmapped dispatch over N shards,
-per-shard parallel recovery, DESIGN.md §6) -- the production registry
-shape for millions of request ids.
+Each served request's id is inserted into a SOFT DurableMap (the
+registry), with the id itself as the recorded value.  A crash loses the
+volatile index but not the registry, so after recovery the server knows
+exactly which requests had completed -- durable linearizability doing
+real work.  --backend picks the registry's index backend ("bucket" = the
+Pallas hash_probe / recovery_scan kernel path, DESIGN.md §4); --shards
+N > 1 swaps in the hash-partitioned ShardedDurableMap (one routed
+dispatch over N shards, per-shard parallel recovery, DESIGN.md §6).
 
---queue upgrades the driver to the durable request/completion SPINE
-(DESIGN.md §7): arrivals are acknowledged by a durable enqueue into a
-request DurableQueue, the server peeks (volatile, zero psync) the batch
-it serves, and after generation the completion path runs response-enqueue
--> registry-insert -> request-dequeue-commit.  The dequeue becomes
-durable only AFTER the completion is recorded, so a crash at any point
-loses no acknowledged request: it is either still live in the request
-queue (will be re-served; the registry dedups re-delivery) or already in
-the registry.  --crash drills exactly that invariant end to end.
+--queue drives the durable request/completion SPINE (DESIGN.md §7):
+arrivals are acknowledged by a durable enqueue into a request
+DurableQueue, the server peeks (volatile, zero psync) the batch it
+serves, and the completion path runs response-enqueue -> registry-insert
+-> request-dequeue-commit.  The dequeue becomes durable only AFTER the
+completion is recorded, so a crash at any point loses no acknowledged
+request: it is either still live in the request queue (re-served; the
+registry dedups re-delivery) or already in the registry.  --crash drills
+exactly that invariant end to end.
 
 --pipeline N (requires --shards > 1) serves the requests in waves through
-the depth-N double-buffered registry (DESIGN.md §6): wave k+1's durable
-ack enqueues and wave k+1's host stage-1 routing run WHILE wave k
-generates on device; each wave's pipelined registry insert is flushed
-durable before that wave's dequeue commit, so the spine's
-no-acknowledged-request-lost ordering (and its exact 4 psyncs/request
-bill) is preserved verbatim under pipelining.
+the depth-N pipelined registry (DESIGN.md §6); each wave's insert is
+flushed durable before that wave's dequeue commit, so the spine's
+no-acknowledged-request-lost ordering holds per wave.  --snapshot-every
+adds background snapshots and the hybrid restart (DESIGN.md §11),
+--autosplit an online shard split under load (DESIGN.md §12), and
+--open-loop hands over to the rate-driven bench_serve harness.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-32b-smoke \
-      --requests 8 --gen 16 [--crash] [--backend bucket] [--shards 8] \
-      [--queue] [--queue-capacity 1024] [--pipeline 2]
+  PYTHONPATH=src python -m repro.launch.serve --requests 8 [--crash] \
+      [--backend bucket] [--shards 8] [--queue] [--queue-capacity 1024] \
+      [--pipeline 2] [--snapshot-every 1] [--autosplit 0.5]
 """
 from __future__ import annotations
 
@@ -42,19 +41,13 @@ import sys
 import tempfile
 import time
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import get_config
 from repro.core import (DurableMap, DurableQueue, ElasticShardedMap,
                         QueueSpec, ShardedDurableMap, SetSpec)
 from repro.launch.compile_cache import use_compile_cache
-from repro.models import model as M
-from repro.models.sharding import CPU_CTX
 from repro.obs import MetricsRegistry
 from repro.store.snapshot import Snapshotter, SnapshotPolicy
-from repro.train import steps as TS
 
 
 def main(argv=None):
@@ -70,10 +63,7 @@ def main(argv=None):
                     help="delegate to repro.launch.bench_serve: open-loop "
                          "Poisson arrivals + BENCH_serve.json (all other "
                          "flags are bench_serve flags)")
-    ap.add_argument("--arch", default="qwen3-32b-smoke")
     ap.add_argument("--requests", type=int, default=8)
-    ap.add_argument("--prompt-len", type=int, default=32)
-    ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--crash", action="store_true")
     ap.add_argument("--backend", default="probe",
                     choices=("probe", "scan", "bucket"),
@@ -119,10 +109,9 @@ def main(argv=None):
     ap.add_argument("--pipeline", type=int, default=1,
                     help="registry pipeline depth (DESIGN.md §6): > 1 "
                          "serves the requests in WAVES through the "
-                         "double-buffered sharded registry -- with "
-                         "--queue, wave k+1's durable ack enqueues while "
-                         "wave k generates on device; requires --shards "
-                         "> 1")
+                         "pipelined sharded registry, each wave flushed "
+                         "durable before its dequeue commit; requires "
+                         "--shards > 1")
     args = ap.parse_args(argv)
     if args.pipeline < 1:
         ap.error("--pipeline must be >= 1")
@@ -137,11 +126,6 @@ def main(argv=None):
                      "(the split frontier commits at dispatch boundaries)")
 
     use_compile_cache()
-    cfg = get_config(args.arch)
-    params = M.init_params(cfg, jax.random.PRNGKey(0))
-    prefill_step, decode_step = TS.make_serve_steps(cfg, CPU_CTX)
-    prefill_step = jax.jit(prefill_step)
-    decode_step = jax.jit(decode_step)
 
     m = MetricsRegistry()     # one snapshot() reaches every structure
     spec = SetSpec(capacity=1024, mode="soft", backend=args.backend)
@@ -234,24 +218,6 @@ def main(argv=None):
             m.counter(f"phase.{name}.psyncs").inc(
                 req_q.psyncs + resp_q.psyncs - qp0)
 
-    max_seq = args.prompt_len + args.gen
-    rng = np.random.default_rng(0)
-    all_toks = rng.integers(0, cfg.vocab, (b, args.prompt_len))
-
-    def generate(tok_rows):
-        """Prefill + decode one wave.  Returns the generated tokens as
-        DEVICE arrays -- no host sync -- so host-side spine work (the
-        next wave's durable ack) can overlap device execution."""
-        caches = M.init_cache(cfg, len(tok_rows), max_seq)
-        caches, logits = prefill_step(
-            params, {"tokens": jnp.asarray(tok_rows, jnp.int32)}, caches)
-        nxt = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
-        out = [nxt]
-        for _ in range(args.gen - 1):
-            caches, nxt, logits = decode_step(params, caches, nxt)
-            out.append(nxt)
-        return jnp.concatenate(out, axis=1)
-
     t0 = time.time()
     if args.pipeline == 1:
         if args.queue:
@@ -265,12 +231,6 @@ def main(argv=None):
             served_ids, ok = req_q.peek(b)
             assert ok.all()
             np.testing.assert_array_equal(served_ids, req_ids)
-        with phase("generate"):
-            gen = generate(all_toks)
-            jax.block_until_ready(gen)
-        dt = time.time() - t0
-        print(f"served {b} requests x {args.gen} tokens in {dt:.2f}s "
-              f"({b * args.gen / dt:.1f} tok/s)")
 
         # durably record completions: one psync per request (SOFT bound).
         # Spine order (--queue): response enqueue -> registry insert ->
@@ -279,19 +239,21 @@ def main(argv=None):
         with phase("record"):
             if args.queue:
                 resp_q.enqueue(req_ids)
-            registry.insert(req_ids, np.asarray(gen[:, -1]))
+            registry.insert(req_ids, req_ids)
         if args.queue:
             with phase("commit"):
                 _, committed = req_q.dequeue(b)
             assert committed.all()
         snapshot_tick()
+        dt = time.time() - t0
+        print(f"served {b} requests in {dt:.2f}s ({b / dt:.1f} req/s)")
     else:
-        # Depth-N pipelined waves (DESIGN.md §6): wave k generates on
-        # device while the host runs wave k+1's durable ack and stage-1
-        # routing.  Spine ordering survives verbatim per wave -- the
-        # pipelined registry insert is FLUSHED (forced durable) before
-        # that wave's dequeue commit, so a crash at any point still
-        # leaves every acknowledged request in the queue or registry.
+        # Depth-N pipelined waves (DESIGN.md §6): wave k+1 is acknowledged
+        # before wave k is recorded.  Spine ordering survives verbatim per
+        # wave -- the pipelined registry insert is FLUSHED (forced
+        # durable) before that wave's dequeue commit, so a crash at any
+        # point still leaves every acknowledged request in the queue or
+        # registry.
         waves = [w for w in np.array_split(np.arange(b),
                                            min(b, 2 * args.pipeline))
                  if len(w)]
@@ -305,17 +267,14 @@ def main(argv=None):
                 served_ids, ok = req_q.peek(len(ids))   # volatile, 0 psync
                 assert np.asarray(ok).all()
                 np.testing.assert_array_equal(served_ids, ids)
-            gen_w = generate(all_toks[idx])             # async, on device
             if args.queue and k + 1 < len(waves):
-                # wave k+1's durable ack rides wave k's device bubble
                 with phase("ack"):
                     acked = np.asarray(req_q.enqueue(req_ids[waves[k + 1]]))
                 assert acked.all(), "admission queue full"
-            last = np.asarray(gen_w)[:, -1]             # force wave k
             with phase("record"):
                 if args.queue:
                     resp_q.enqueue(ids)
-                registry.insert(ids, last)              # staged, lazy
+                registry.insert(ids, ids)               # staged, lazy
                 registry.pipeline_flush()   # durable BEFORE dequeue commit
             if args.queue:
                 with phase("commit"):
@@ -323,9 +282,9 @@ def main(argv=None):
                 assert np.asarray(committed).all()
             snapshot_tick()
         dt = time.time() - t0
-        print(f"served {b} requests x {args.gen} tokens in {len(waves)} "
-              f"waves (depth-{args.pipeline} registry pipeline) in "
-              f"{dt:.2f}s ({b * args.gen / dt:.1f} tok/s)")
+        print(f"served {b} requests in {len(waves)} waves "
+              f"(depth-{args.pipeline} registry pipeline) in "
+              f"{dt:.2f}s ({b / dt:.1f} req/s)")
     # end-of-run summary: everything below reads the ONE metrics snapshot
     # (DESIGN.md §10) -- the same numbers an operator's sink would see
     snap = m.snapshot()

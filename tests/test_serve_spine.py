@@ -29,7 +29,7 @@ STEPS = ("after_ack", "after_peek", "after_resp_enqueue",
 
 
 def _process(ids):
-    """Stand-in for generation: the recorded completion value."""
+    """Stand-in for serving a request: the recorded completion value."""
     return (ids * 2 + 1).astype(np.int32)
 
 
@@ -147,7 +147,7 @@ def test_spine_psync_bound():
 
 def test_pipelined_spine_exactly_once_and_psync_bound():
     """The ``serve.py --pipeline`` wave loop (DESIGN.md §6): wave k+1's
-    durable ack enqueues while wave k "generates", and each wave's
+    durable ack enqueues before wave k is recorded, and each wave's
     pipelined registry insert is flushed durable BEFORE that wave's
     dequeue commit.  Exactly-once completion and the exact 4
     psyncs/request bill survive pipelining unchanged."""
@@ -161,7 +161,7 @@ def test_pipelined_spine_exactly_once_and_psync_bound():
     for k, wave in enumerate(waves):
         served, ok = req_q.peek(len(wave))          # volatile, zero psync
         np.testing.assert_array_equal(served[np.asarray(ok)], wave)
-        if k + 1 < len(waves):   # ack wave k+1 during wave k's generation
+        if k + 1 < len(waves):   # ack wave k+1 before wave k is recorded
             assert np.asarray(req_q.enqueue(waves[k + 1])).all()
         resp_q.enqueue(wave)
         registry.insert(wave, _process(wave))       # staged, lazy
