@@ -3,8 +3,9 @@
 Two regimes (DESIGN.md §5):
 
   bulk         ``build_buckets`` / ``bucket_init`` pack the whole node pool
-               into the (NB, W) table -- an O(N log N) argsort repack paid
-               ONLY at state construction and recovery.
+               into the (NB, W) table and the stash -- two sorts that carry
+               their operands and a scan, no gather or scatter -- paid ONLY
+               on a full rebuild at recovery (the index is born empty).
   incremental  ``bucket_insert`` / ``bucket_remove`` maintain the same table
                with O(B*W) per-lane scatter writes -- the hot path.  A lane
                claims the first free way of its bucket, spills to the dense
@@ -21,58 +22,77 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core.nvm import hash32, EMPTY, VALID
 from repro.kernels.hash_probe.kernel import probe_pallas
 from repro.kernels.hash_probe.ref import probe_ref
 
 
-@functools.partial(jax.jit, static_argnames=("nb", "w"))
-def build_buckets(keys: jax.Array, cur: jax.Array, nb: int = 1024, w: int = 8):
-    """Pack live nodes of a durable-set pool into a (NB, W) bucket table.
+@functools.partial(jax.jit, static_argnames=("nb", "w", "s"))
+def build_buckets(keys: jax.Array, cur: jax.Array, nb: int = 1024, w: int = 8,
+                  s: Optional[int] = None):
+    """Pack the live nodes of a durable-set pool into a (NB, W) table.
 
-    Deterministic way assignment: rank of each node among same-bucket live
-    nodes (computed with a sort), overflowing entries dropped into the dense
-    stash handled by the wrapper (rare under load factor <= 0.5)."""
+    Each bucket's ways hold its live nodes in ascending node id, the rest
+    EMPTY (key 0); the live nodes past a bucket's ``w`` ways spill.
+    Returns (bkeys, bids, spill count); given ``s``, also the first ``s``
+    spilled nodes by node id, (skeys, sids), EMPTY-padded.
+
+    No gather and no scatter: every bucket gets ``w`` dummy entries with
+    ids above every node, so that one sort by (bucket, id), carrying the
+    ids and keys, leaves each bucket's group at least ``w`` long with its
+    live nodes first.  A scan ranks each entry within its group; a second
+    sort moves the ``w`` first of every group to the front -- the table's
+    rows, in order -- followed by the spilled nodes in id order."""
     n = keys.shape[0]
+    m = nb * w
     assert n < (1 << 24), "pool size exceeds the f32-exact node-id budget"
-    live = cur == VALID
+    assert 3 * (n + m) < (1 << 31), "bucket table exceeds the i32 sort key"
     bucket = (hash32(keys) % jnp.uint32(nb)).astype(jnp.int32)
-    bucket = jnp.where(live, bucket, nb)          # dead nodes -> overflow bin
-    order = jnp.argsort(bucket)                   # stable: groups same bucket
-    sorted_b = bucket[order]
-    # rank within bucket group
-    idx = jnp.arange(n, dtype=jnp.int32)
-    group_start = jnp.full((nb + 1,), n, jnp.int32).at[sorted_b].min(
-        idx, mode="drop")
-    rank = idx - group_start[jnp.clip(sorted_b, 0, nb)]
-    ok = (sorted_b < nb) & (rank < w)
-    flat = jnp.where(ok, sorted_b * w + rank, nb * w)
-    bkeys = jnp.zeros((nb * w,), jnp.int32).at[flat].set(
-        keys[order], mode="drop").reshape(nb, w)
-    bids = jnp.full((nb * w,), -1, jnp.int32).at[flat].set(
-        order.astype(jnp.int32), mode="drop").reshape(nb, w)
-    overflow = jnp.sum((sorted_b < nb) & (rank >= w))
-    return bkeys, bids, overflow
+    bucket = jnp.where(cur == VALID, bucket, nb)       # dead nodes sort last
+    ids = jnp.arange(n, dtype=jnp.int32)
+    sb, sid, sk = lax.sort(
+        (jnp.concatenate([bucket, jnp.repeat(jnp.arange(nb, dtype=jnp.int32),
+                                             w)]),
+         jnp.concatenate([ids, n + jnp.tile(jnp.arange(w, dtype=jnp.int32),
+                                            nb)]),
+         jnp.concatenate([keys, jnp.zeros((m,), keys.dtype)])),
+        num_keys=2, is_stable=False)
+    pos = jnp.arange(n + m, dtype=jnp.int32)
+    first = jnp.concatenate([jnp.ones((1,), jnp.bool_), sb[1:] != sb[:-1]])
+    rank = pos - lax.cummax(jnp.where(first, pos, 0))
+    kept = (sb < nb) & (rank < w)
+    spilled = (sb < nb) & (rank >= w) & (sid < n)
+    # the kept entries in table order, then the spilled nodes by id, then
+    # the dead nodes and the unused dummies
+    _, tid, tk = lax.sort(
+        (jnp.where(kept, pos, jnp.where(spilled, n + m + sid,
+                                        2 * (n + m) + pos)), sid, sk),
+        num_keys=1, is_stable=False)
+    node = tid[:m] < n
+    bkeys = jnp.where(node, tk[:m], 0).reshape(nb, w)
+    bids = jnp.where(node, tid[:m], EMPTY).reshape(nb, w)
+    spill = jnp.sum(spilled.astype(jnp.int32))
+    if s is None:
+        return bkeys, bids, spill
+    k = min(s, n)                          # at most n nodes can spill
+    got = jnp.arange(k) < spill
+    skeys = jnp.pad(jnp.where(got, tk[m:m + k], 0), (0, s - k))
+    sids = jnp.pad(jnp.where(got, tid[m:m + k], EMPTY), (0, s - k),
+                   constant_values=EMPTY)
+    return bkeys, bids, spill, skeys, sids
 
 
 @functools.partial(jax.jit, static_argnames=("nb", "w", "s"))
 def bucket_init(keys: jax.Array, cur: jax.Array, *, nb: int, w: int, s: int):
     """Bulk build of the full incremental index: (NB, W) bucket table plus
-    the dense stash holding the live nodes that overflowed their bucket.
+    the dense stash holding the first ``s`` live nodes, by node id, that
+    overflowed their bucket (``build_buckets``).
     Returns (bkeys, bids, skeys, sids, stash_n, overflow) -- overflow is
     True when more than ``s`` nodes spilled (data would be unreachable)."""
-    bkeys, bids, _ = build_buckets(keys, cur, nb=nb, w=w)
-    n = keys.shape[0]
-    flat = bids.reshape(-1)
-    in_table = jnp.zeros((n,), jnp.bool_).at[
-        jnp.where(flat >= 0, flat, n)].set(True, mode="drop")
-    stashed = (cur == VALID) & ~in_table
-    spill = jnp.sum(stashed.astype(jnp.int32))
-    idx = jnp.where(stashed, size=s, fill_value=-1)[0].astype(jnp.int32)
-    got = idx >= 0
-    sids = jnp.where(got, idx, EMPTY)
-    skeys = jnp.where(got, keys[jnp.clip(idx, 0)], 0)
+    bkeys, bids, spill, skeys, sids = build_buckets(keys, cur, nb=nb, w=w,
+                                                    s=s)
     return bkeys, bids, skeys, sids, jnp.minimum(spill, s), spill > s
 
 
